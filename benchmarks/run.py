@@ -99,6 +99,10 @@ def main() -> None:
 
     import importlib
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     all_results = {}
     print("name,us_per_call,derived")
     n_failed = 0

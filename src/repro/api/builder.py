@@ -132,7 +132,7 @@ class PipelineBuilder:
         against a device-resident dictionary, and commits them through
         the pattern-aware GRAPHPUSH path (`commit_compressed`).  When no
         stage is passed one is created at build time from the keyword
-        args (capacity, star_min, hot_min, ttl, use_kernel); retrieve it
+        args (capacity, star_min, hot_min, ttl); retrieve it
         via `.dictionary_stage` after build()."""
         self._dict_stage = stage
         self._compression_kw = dict(kw)

@@ -212,15 +212,13 @@ class DictionaryStage:
     name = "dictionary"
 
     def __init__(self, capacity: int = 4096, star_min: int = 4,
-                 hot_min: int = 2, ttl: int = 64,
-                 use_kernel: Optional[bool] = None):
+                 hot_min: int = 2, ttl: int = 64):
         from repro.telemetry.spans import NULL_REGISTRY
 
         self.capacity = int(capacity)
         self.star_min = int(star_min)
         self.hot_min = int(hot_min)
         self.ttl = int(ttl)
-        self.use_kernel = use_kernel
         self.dct: Optional[PatternDictionary] = None
         self.ticks_seen = 0
         self.rewrites = 0
@@ -258,7 +256,7 @@ class DictionaryStage:
         with tel.span("rewrite.mine"):
             fan_out, fan_in, flags, psig = ops.pattern_mine(
                 et.src, et.dst, et.etype, et.count, et.edge_valid,
-                self.star_min, self.hot_min, use_kernel=self.use_kernel)
+                self.star_min, self.hot_min)
         with tel.span("rewrite.lookup"):
             keys = mix_keys(et.src, et.dst, et.etype)
             self.dct, hit, eslot, sslot, dslot, entry = dict_lookup(

@@ -427,10 +427,5 @@ def make_distributed_ingest(mesh):
     )
     in_specs = (store_specs, P("data"), P("data"), P("data"), P("data"))
     out_specs = (store_specs, P())
-    if hasattr(jax, "shard_map"):  # jax >= 0.6
-        return jax.shard_map(local_ingest, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(local_ingest, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    return jax.shard_map(local_ingest, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

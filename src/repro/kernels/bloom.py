@@ -68,7 +68,7 @@ def _build_kernel(keys_ref, bitmap_in_ref, bitmap_ref, *, words: int):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def bloom_probe(keys: jax.Array, bitmap: jax.Array, interpret: bool = True):
+def bloom_probe(keys: jax.Array, bitmap: jax.Array, interpret: bool = False):
     """keys (n,) uint32; bitmap (W, LANES) uint32. Returns hit mask (n,)."""
     n = keys.shape[0]
     W = bitmap.shape[0]
@@ -88,7 +88,7 @@ def bloom_probe(keys: jax.Array, bitmap: jax.Array, interpret: bool = True):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def bloom_build(keys: jax.Array, bitmap: jax.Array, interpret: bool = True):
+def bloom_build(keys: jax.Array, bitmap: jax.Array, interpret: bool = False):
     """Insert keys; returns the updated bitmap."""
     n = keys.shape[0]
     W = bitmap.shape[0]
@@ -105,6 +105,14 @@ def bloom_build(keys: jax.Array, bitmap: jax.Array, interpret: bool = True):
         out_shape=jax.ShapeDtypeStruct((W, LANES), jnp.uint32),
         interpret=interpret,
     )(keys, bitmap)
+
+
+def bloom_diversity(keys: jax.Array, bitmap: jax.Array, interpret: bool = False):
+    """(rho, new_bitmap): fraction of unseen keys + updated filter —
+    the pre-commit diversity signal for the buffer controller."""
+    hit = bloom_probe(keys, bitmap, interpret=interpret)
+    rho = 1.0 - hit.mean(dtype=jnp.float32)
+    return rho, bloom_build(keys, bitmap, interpret=interpret)
 
 
 def init_bitmap(rows: int = 64) -> jax.Array:

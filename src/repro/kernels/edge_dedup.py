@@ -5,7 +5,7 @@ TPU: instead of the paper's serial hash map, keys are sorted in VMEM by
 a bitonic network (log^2 n compare-exchange stages, pure VPU min/max on
 (n/2j, 2, j)-reshaped vectors — no data-dependent control flow), then
 run heads are marked by a shifted comparison.  Segment counting runs in
-XLA afterwards (repro.kernels.ops.dedup_sorted_counts) where
+XLA afterwards (`dedup_sorted_counts` below) where
 segment-sum is already optimal.
 
 VMEM budget: one uint32 key vector + one index vector; n <= 65536 keys
@@ -59,7 +59,7 @@ def _dedup_kernel(keys_ref, sorted_ref, order_ref, head_ref, *, n: int):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def sort_dedup(keys: jax.Array, interpret: bool = True):
+def sort_dedup(keys: jax.Array, interpret: bool = False):
     """keys: (n,) uint32, n a power of two.
     Returns (sorted_keys, order, head_flags)."""
     n = keys.shape[0]
@@ -81,3 +81,12 @@ def sort_dedup(keys: jax.Array, interpret: bool = True):
         ],
         interpret=interpret,
     )(keys)
+
+
+def dedup_sorted_counts(sorted_keys: jax.Array, head: jax.Array):
+    """Per-run counts from the kernel's (sorted, head) output."""
+    n = sorted_keys.shape[0]
+    run = jnp.cumsum(head) - 1
+    counts = jax.ops.segment_sum(jnp.ones((n,), jnp.int32), jnp.clip(run, 0, n - 1), num_segments=n)
+    n_unique = head.sum()
+    return counts, n_unique

@@ -81,7 +81,7 @@ def flash_attention(
     window: Optional[int] = None,
     block_q: int = 512,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     BH, S, d = q.shape
     block_q = min(block_q, S)

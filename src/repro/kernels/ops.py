@@ -1,115 +1,55 @@
-"""Public jit'd wrappers over the Pallas kernels.
+"""The main path's hot ops, each with one declared implementation.
 
-On this CPU container every kernel runs through the Pallas interpreter
-(`interpret=True`, the validation mode); on a real TPU the same call
-sites compile the Mosaic kernels (`interpret=False`).  `ON_TPU` flips
-the default.
+`IMPL` names what each op runs.  A Pallas kernel is declared for an op
+only where it compiles for TPU v5e at the main path's widths and is
+bit-exact with its jnp oracle.  None does yet (ROADMAP S7 records each
+kernel's compiler refusal), so every op runs its XLA implementation,
+the same one on TPU and on CPU.
+
+The kernels stay in their modules (`repro.kernels.upsert`, `sampler`,
+`sketch`, `pattern_mine`, ...) with their interpret-mode tests; they
+run in interpret mode only when a caller passes `interpret=True`.
+Nothing here calls a kernel.
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional, Tuple
-
-import jax
-import jax.numpy as jnp
-
-from repro.kernels import bloom as _bloom
-from repro.kernels import edge_dedup as _dedup
-from repro.kernels import flash_attention as _flash
 from repro.kernels import pattern_mine as _mine
 from repro.kernels import sampler as _sampler
 from repro.kernels import sketch as _sketch
-from repro.kernels import ssd_scan as _ssd
 from repro.kernels import upsert as _upsert
 
-ON_TPU = any(d.platform == "tpu" for d in jax.devices())
-_INTERP = not ON_TPU
+# op -> implementation on every backend ("xla" = the jnp oracle, compiled
+# by XLA); a compiling, bit-exact kernel would be named here instead
+IMPL = {
+    "fused_upsert": "xla",
+    "traffic_sample": "xla",
+    "sketch_scatter": "xla",
+    "pattern_mine": "xla",
+}
 
 
-def sort_dedup(keys: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """(sorted, order, head) for power-of-two uint32 key vectors."""
-    return _dedup.sort_dedup(keys, interpret=_INTERP)
-
-
-def dedup_sorted_counts(sorted_keys: jax.Array, head: jax.Array):
-    """Per-run counts from the kernel's (sorted, head) output."""
-    n = sorted_keys.shape[0]
-    run = jnp.cumsum(head) - 1
-    counts = jax.ops.segment_sum(jnp.ones((n,), jnp.int32), jnp.clip(run, 0, n - 1), num_segments=n)
-    n_unique = head.sum()
-    return counts, n_unique
-
-
-def bloom_build(keys: jax.Array, bitmap: jax.Array) -> jax.Array:
-    return _bloom.bloom_build(keys, bitmap, interpret=_INTERP)
-
-
-def bloom_probe(keys: jax.Array, bitmap: jax.Array) -> jax.Array:
-    return _bloom.bloom_probe(keys, bitmap, interpret=_INTERP)
-
-
-def bloom_diversity(keys: jax.Array, bitmap: jax.Array):
-    """(rho, new_bitmap): fraction of unseen keys + updated filter —
-    the pre-commit diversity signal for the buffer controller."""
-    hit = bloom_probe(keys, bitmap)
-    rho = 1.0 - hit.mean(dtype=jnp.float32)
-    return rho, bloom_build(keys, bitmap)
-
-
-def pattern_mine(src, dst, etype, count, valid, star_min, hot_min,
-                 use_kernel=None):
-    """Frequent-substructure mining over a dedup'd batch (GraphZip
-    front-end, repro.compress): (fan_out, fan_in, flags, psig) per
-    edge.  The jnp oracle is the fast path off-TPU."""
-    use_kernel = ON_TPU if use_kernel is None else use_kernel
-    if use_kernel:
-        return _mine.pattern_mine(src, dst, etype, count, valid,
-                                  star_min, hot_min, interpret=_INTERP)
-    return _mine.pattern_mine_ref(src, dst, etype, count, valid,
-                                  star_min, hot_min)
-
-
-def fused_upsert(table_keys, keys, valid, n_probes, use_kernel=None):
+def fused_upsert(table_keys, keys, valid, n_probes):
     """Fused lookup-or-insert (GRAPHPUSH commit hot path): one probe
     sweep per table instead of lookup-then-insert.  Returns
-    (table_keys', slot (-1 = dropped), is_new).  The jnp oracle is the
-    fast path off-TPU (interpret-mode Pallas is validation-only)."""
-    use_kernel = ON_TPU if use_kernel is None else use_kernel
-    if use_kernel:
-        return _upsert.fused_upsert(table_keys, keys, valid, n_probes,
-                                    interpret=_INTERP)
+    (table_keys', slot (-1 = dropped), is_new)."""
     return _upsert.fused_upsert_ref(table_keys, keys, valid, n_probes)
 
 
-def traffic_sample(seed, ctr0, n: int, iparams, fparams, use_kernel=None):
+def traffic_sample(seed, ctr0, n: int, iparams, fparams):
     """Counter-based traffic-id block for the workload generator
-    (repro.workloads): (uid, tag, mention, u_dup, u_dupi).  One fused
-    sampling launch per block; deterministic in (seed, ctr0)."""
-    use_kernel = ON_TPU if use_kernel is None else use_kernel
-    if use_kernel:
-        return _sampler.traffic_ids(seed, ctr0, n, iparams, fparams,
-                                    interpret=_INTERP)
+    (repro.workloads): (uid, tag, mention, u_dup, u_dupi).
+    Deterministic in (seed, ctr0)."""
     return _sampler.traffic_ids_ref(seed, ctr0, n, iparams, fparams)
 
 
 def sketch_scatter(edge_w, out_deg, in_deg, r, c, cnt):
     """Graph-sketch scatter-add hot path (repro.query.sketch)."""
-    return _sketch.sketch_scatter(edge_w, out_deg, in_deg, r, c, cnt,
-                                  interpret=_INTERP)
+    return _sketch.scatter_add(edge_w, out_deg, in_deg, r, c, cnt)
 
 
-def flash_attention(
-    q: jax.Array, k: jax.Array, v: jax.Array,
-    causal: bool = True, window: Optional[int] = None,
-    block_q: int = 512, block_k: int = 512,
-) -> jax.Array:
-    """(BH,S,d) attention; MQA/GQA callers broadcast KV beforehand."""
-    return _flash.flash_attention(
-        q, k, v, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, interpret=_INTERP,
-    )
-
-
-def ssd_scan(x, dt, A, B, C, chunk: int = 128):
-    """(y, final_state) Mamba2 SSD over (BH,S,*) inputs."""
-    return _ssd.ssd_scan(x, dt, A, B, C, chunk=chunk, interpret=_INTERP)
+def pattern_mine(src, dst, etype, count, valid, star_min, hot_min):
+    """Frequent-substructure mining over a dedup'd batch (GraphZip
+    front-end, repro.compress): (fan_out, fan_in, flags, psig) per
+    edge."""
+    return _mine.pattern_mine_ref(src, dst, etype, count, valid,
+                                  star_min, hot_min)

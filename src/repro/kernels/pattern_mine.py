@@ -116,8 +116,8 @@ def mine_body(src, dst, etype, count, valid, star_min, hot_min, sort_fn):
 # ---------------------------------------------------------------- oracle
 @jax.jit
 def pattern_mine_ref(src, dst, etype, count, valid, star_min, hot_min):
-    """jnp oracle (and the CPU hot path — interpret-mode Pallas is the
-    validation path, not the fast path; see repro.kernels.ops)."""
+    """jnp oracle, and what the main path runs on every backend
+    (see repro.kernels.ops)."""
     return mine_body(src, dst, etype, count, valid,
                      jnp.asarray(star_min, jnp.int32),
                      jnp.asarray(hot_min, jnp.int32), jnp.sort)
@@ -159,7 +159,7 @@ def _mine_kernel(params_ref, src_ref, dst_ref, etype_ref, count_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def pattern_mine(src, dst, etype, count, valid, star_min, hot_min,
-                 interpret: bool = True):
+                 interpret: bool = False):
     """Pattern mining through the Pallas kernel.
 
     src/dst (n,) key dtype; etype/count (n,) int32; valid (n,) bool;

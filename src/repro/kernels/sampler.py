@@ -68,8 +68,12 @@ def counter_mix(seed: jax.Array, ctr: jax.Array) -> jax.Array:
 
 
 def uniform01(bits: jax.Array) -> jax.Array:
-    """uint32 bits -> float32 uniforms in [0, 1) (24-bit mantissa)."""
-    return (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    """uint32 bits -> float32 uniforms in [0, 1) (24-bit mantissa).
+
+    The 24-bit value converts through int32, exactly: Mosaic has no
+    uint32 -> float32 cast."""
+    return ((bits >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32)
+            * jnp.float32(1.0 / (1 << 24)))
 
 
 def zipf_rank(u: jax.Array, n, a) -> jax.Array:
@@ -121,8 +125,8 @@ def _lanes(ctr0, n: int):
 
 @functools.partial(jax.jit, static_argnames=("n",))
 def traffic_ids_ref(seed, ctr0, n: int, iparams, fparams):
-    """jnp oracle (and the CPU fast path — interpret-mode Pallas is the
-    validation path, not the fast path; see repro.kernels.ops).
+    """jnp oracle, and what the main path runs on every backend
+    (see repro.kernels.ops).
 
     iparams (4,) int32: n_users, n_tags, burst_ntags, topic_base;
     fparams (5,) float32: a_user, a_tag, a_mention, burst_frac, copy_frac.
@@ -148,7 +152,7 @@ def _traffic_kernel(seed_ref, ip_ref, fp_ref, lanes_ref, pos_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("n", "interpret"))
-def traffic_ids(seed, ctr0, n: int, iparams, fparams, interpret: bool = True):
+def traffic_ids(seed, ctr0, n: int, iparams, fparams, interpret: bool = False):
     """Fused traffic-id sampling through the Pallas kernel.
 
     Same contract as `traffic_ids_ref`; one launch per block, all

@@ -11,8 +11,9 @@ D=4, W=256).
 Row/col hash coordinates are precomputed outside (cheap VPU work, and
 the host-side oracle shares them); the kernel owns the memory-bound
 scatter.  Integer scatter-add is order-independent, so the kernel is
-bit-exact against the jnp oracle `repro.query.sketch.sketch_scatter_ref`
-by construction — tests assert it.
+bit-exact against its jnp oracle (`scatter_add` run outside pallas_call,
+which is `repro.kernels.ops.sketch_scatter`) by construction — tests
+assert it.
 """
 from __future__ import annotations
 
@@ -25,8 +26,8 @@ from jax.experimental import pallas as pl
 
 def scatter_add(edge_w, out_deg, in_deg, r, c, cnt):
     """The pure scatter-add body, shared verbatim by the Pallas kernel
-    and the jnp oracle (repro.query.sketch.sketch_scatter_ref) so the
-    two can never drift."""
+    and the jnp oracle (`repro.kernels.ops.sketch_scatter`) so the two
+    can never drift."""
     W = edge_w.shape[1]
     depth = jax.lax.broadcasted_iota(jnp.int32, r.shape, 0)
     cnt_b = jnp.broadcast_to(cnt[None, :], r.shape)
@@ -52,7 +53,7 @@ def _scatter_kernel(ew_ref, od_ref, id_ref, r_ref, c_ref, cnt_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def sketch_scatter(edge_w: jax.Array, out_deg: jax.Array, in_deg: jax.Array,
                    r: jax.Array, c: jax.Array, cnt: jax.Array,
-                   interpret: bool = True):
+                   interpret: bool = False):
     """One sketch update: (edge_w', out_deg', in_deg').
 
     edge_w (D, W, W) int32; out_deg/in_deg (D, W) int32;

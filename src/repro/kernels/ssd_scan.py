@@ -71,7 +71,7 @@ def ssd_scan(
     B: jax.Array,   # (BH, S, N)
     C: jax.Array,   # (BH, S, N)
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     BH, S, p = x.shape
     N = B.shape[-1]

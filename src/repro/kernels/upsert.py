@@ -73,8 +73,8 @@ def upsert_sweep(table_keys: jax.Array, keys: jax.Array, valid: jax.Array,
 @jax.jit
 def fused_upsert_ref(table_keys: jax.Array, keys: jax.Array, valid: jax.Array,
                      n_probes: jax.Array):
-    """jnp oracle (and the CPU hot path — interpret-mode Pallas is the
-    validation path, not the fast path; see repro.kernels.ops)."""
+    """jnp oracle, and what the main path runs on every backend
+    (see repro.kernels.ops)."""
     return upsert_sweep(table_keys, keys, valid,
                         jnp.asarray(n_probes, jnp.int32))
 
@@ -90,7 +90,7 @@ def _upsert_kernel(probes_ref, table_ref, keys_ref, valid_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_upsert(table_keys: jax.Array, keys: jax.Array, valid: jax.Array,
-                 n_probes: jax.Array, interpret: bool = True):
+                 n_probes: jax.Array, interpret: bool = False):
     """Fused upsert through the Pallas kernel.
 
     table_keys (cap,) key dtype (0 = empty); keys (n,) unique batch;

@@ -17,6 +17,7 @@ import numpy as np
 from repro.api import PipelineBuilder
 from repro.configs.paper_ingest import IngestConfig
 from repro.ingest.sources import BurstyTweetSource
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -38,6 +39,7 @@ def main(argv=None):
     if args.shards > 1 and args.uncontrolled:
         ap.error("--shards requires the controlled pipeline "
                  "(drop --uncontrolled)")
+    enable_compile_cache()
 
     cfg = IngestConfig(cpu_max=args.cpu_max, mean_rate=args.rate,
                        burst_multiplier=args.burst)

@@ -27,6 +27,7 @@ import numpy as np
 from repro.api import PipelineBuilder, GraphStoreSink
 from repro.configs.paper_ingest import IngestConfig
 from repro.ingest.sources import BurstyTweetSource
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -49,6 +50,7 @@ def main(argv=None):
     ap.add_argument("--dryrun", action="store_true",
                     help="tiny end-to-end run (CI smoke)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.dryrun:
         args.ticks = min(args.ticks, 25)
         args.node_cap, args.edge_cap = 1 << 11, 1 << 12

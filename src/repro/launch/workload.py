@@ -60,6 +60,7 @@ def main(argv=None):
                     help="tiny end-to-end run (CI smoke)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.workloads import list_scenarios, run_scenario
 
     if args.list:
@@ -67,6 +68,7 @@ def main(argv=None):
             print(f"{s.name:18s} {s.description}")
         return 0
 
+    enable_compile_cache()
     if args.dryrun:
         args.ticks = min(args.ticks or 60, 60)
         args.node_cap = args.node_cap or 1 << 12

@@ -19,9 +19,9 @@ and top-k queries can be answered live, without touching the store:
 All shapes are static, the whole state is a pytree, and one update
 absorbs one compressed `EdgeTable` — the same batches the store
 commits, so sketch totals are directly comparable to store contents.
-Updates route through the Pallas scatter kernel on TPU
-(`repro.kernels.sketch`) or the pure-jnp oracle path here; both are
-bit-exact (integer scatter-add is order-independent).
+Updates run the scatter-add through `repro.kernels.ops.sketch_scatter`
+(XLA on every backend; the Pallas kernel in `repro.kernels.sketch` is
+bit-exact with it but does not compile for TPU, see ROADMAP S7).
 
 Guarantees (tested in tests/test_query.py):
   sketch_degree(u)         >= weighted degree of u in the store
@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import compression as C
+from repro.kernels import ops
 
 
 @jax.tree_util.register_pytree_node_class
@@ -53,7 +54,9 @@ class GraphSketch:
     n_updates: jax.Array  # scalar int32: total edge count absorbed
 
     def tree_flatten(self):
-        return dataclasses.astuple(self), None
+        # shallow, like GraphStore: astuple() deep-copies every leaf
+        return tuple(getattr(self, f.name)
+                     for f in dataclasses.fields(self)), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -113,64 +116,63 @@ def node_hash(keys: jax.Array, depth: int, width: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def sketch_scatter_ref(edge_w, out_deg, in_deg, r, c, cnt):
-    """Pure-jnp oracle of the Pallas kernel: literally the same body
-    (`repro.kernels.sketch.scatter_add`), run outside pallas_call."""
-    from repro.kernels.sketch import scatter_add
-
-    return scatter_add(edge_w, out_deg, in_deg, r, c, cnt)
-
-
 def _merge_top_k(hh_keys, hh_counts, cand_keys, cand_counts):
     """Merge candidates into the K-slot heavy-hitter table.
 
-    Sort-based, fixed shapes: concat, dedup by key keeping the max
-    count (CMS estimates only grow, so max = freshest), then top-K.
-    Key 0 marks empty slots on both sides."""
+    Fixed shapes: dedup by key keeping the max count (CMS estimates
+    only grow, so max = freshest), then the top K by count, ties to the
+    smaller key.  Key 0 marks empty slots on both sides.
+
+    Only the candidates are sorted, at their own length: the TPU
+    compiler takes minutes over one sort of the K-longer concatenation
+    at 16k candidates.  The table folds into the sorted candidates by
+    binary search, and the top K of the union is the top K of the best
+    K of each side."""
     K = hh_keys.shape[0]
     kd = hh_keys.dtype
     sent = C.sentinel_for(kd)
-    keys = jnp.concatenate([hh_keys, cand_keys])
-    cnts = jnp.concatenate([hh_counts.astype(jnp.int32),
-                            cand_counts.astype(jnp.int32)])
-    m = keys.shape[0]
-    masked = jnp.where(keys != 0, keys, sent)
+    m = cand_keys.shape[0]
+    # candidates: sorted unique keys (sentinel tail), best count each
+    masked = jnp.where(cand_keys != 0, cand_keys, sent)
     order = jnp.argsort(masked)
-    sk, sc = masked[order], cnts[order]
+    sk, sc = masked[order], cand_counts.astype(jnp.int32)[order]
     is_valid = sk != sent
     head = jnp.concatenate([jnp.ones((1,), bool), sk[1:] != sk[:-1]]) & is_valid
     run = jnp.clip(jnp.cumsum(head.astype(jnp.int32)) - 1, 0, m - 1)
     best = jax.ops.segment_max(jnp.where(is_valid, sc, -1), run, num_segments=m)
     first = jax.ops.segment_min(jnp.where(head, jnp.arange(m), m), run,
                                 num_segments=m)
-    fp = jnp.clip(first, 0, m - 1)
-    n_unique = jnp.sum(head.astype(jnp.int32))
-    live = jnp.arange(m) < n_unique
-    run_keys = jnp.where(live, sk[fp], 0)
+    live = jnp.arange(m) < jnp.sum(head.astype(jnp.int32))
+    run_keys = jnp.where(live, sk[jnp.clip(first, 0, m - 1)], sent)
     run_best = jnp.where(live, best, -1)
-    top_c, top_i = jax.lax.top_k(run_best, K)
+    # table entries already among the candidates keep the larger count
+    hk = jnp.where(hh_keys != 0, hh_keys, sent)
+    pos = jnp.clip(jnp.searchsorted(run_keys, hk).astype(jnp.int32), 0, m - 1)
+    found = (run_keys[pos] == hk) & (hk != sent)
+    run_best = run_best.at[jnp.where(found, pos, m)].max(
+        hh_counts.astype(jnp.int32), mode="drop")
+    rest = jnp.where(found | (hk == sent), -1, hh_counts.astype(jnp.int32))
+    # top_k breaks ties to the lower index, i.e. the smaller run key
+    cand_c, cand_i = jax.lax.top_k(run_best, min(K, m))
+    neg_c, keys = jax.lax.sort(
+        (-jnp.concatenate([cand_c, rest]),
+         jnp.concatenate([run_keys[cand_i], hk])), num_keys=2)
+    top_c = -neg_c[:K]
     keep = top_c > 0
-    return (jnp.where(keep, run_keys[top_i], 0),
+    return (jnp.where(keep, keys[:K], 0),
             jnp.where(keep, top_c, 0).astype(jnp.int32))
 
 
-@partial(jax.jit, static_argnames=("use_kernel",))
-def sketch_update(sketch: GraphSketch, et, use_kernel: bool = False) -> GraphSketch:
+@jax.jit
+def sketch_update(sketch: GraphSketch, et) -> GraphSketch:
     """Absorb one compressed `EdgeTable` (the same batch the store
-    commits).  `use_kernel=True` routes the scatter hot path through
-    the Pallas kernel (default on TPU via `SketchStage`)."""
+    commits)."""
     D, W = sketch.depth, sketch.width
     cnt = jnp.where(et.edge_valid, et.count, 0).astype(jnp.int32)
     r = node_hash(et.src, D, W)
     c = node_hash(et.dst, D, W)
-    if use_kernel:
-        from repro.kernels import ops
-
-        ew, od, idg = ops.sketch_scatter(
-            sketch.edge_w, sketch.out_deg, sketch.in_deg, r, c, cnt)
-    else:
-        ew, od, idg = sketch_scatter_ref(
-            sketch.edge_w, sketch.out_deg, sketch.in_deg, r, c, cnt)
+    ew, od, idg = ops.sketch_scatter(
+        sketch.edge_w, sketch.out_deg, sketch.in_deg, r, c, cnt)
 
     # heavy hitters: this batch's (deduplicated) nodes compete by
     # their post-update CMS degree estimate

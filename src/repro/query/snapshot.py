@@ -70,7 +70,9 @@ class GraphSnapshot:
     n_edges: jax.Array  # scalar int32 (unique (src,dst,etype) triples)
 
     def tree_flatten(self):
-        return dataclasses.astuple(self), None
+        # shallow, like GraphStore: astuple() deep-copies every leaf
+        return tuple(getattr(self, f.name)
+                     for f in dataclasses.fields(self)), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):

@@ -81,17 +81,13 @@ class SketchStage(_SketchQueries):
     def __init__(self, sketch: Optional[GraphSketch] = None,
                  mapping: Optional[MappingSpec] = None,
                  depth: int = 4, width: int = 256, hh_slots: int = 64,
-                 max_edges_per_batch: int = 8_192,
-                 use_kernel: Optional[bool] = None):
-        from repro.kernels import ops
-
+                 max_edges_per_batch: int = 8_192):
         from repro.telemetry.spans import NULL_REGISTRY
 
         self.sketch = sketch if sketch is not None else init_sketch(
             depth=depth, width=width, hh_slots=hh_slots)
         self.mapping = mapping or tweet_mapping()
         self.max_edges_per_batch = max_edges_per_batch
-        self.use_kernel = ops.ON_TPU if use_kernel is None else use_kernel
         self.ticks_seen = 0
         self.telemetry = NULL_REGISTRY
 
@@ -106,8 +102,7 @@ class SketchStage(_SketchQueries):
                     hi = min(lo + self.max_edges_per_batch, raw.n_edges)
                     cap = max(64, 1 << int(np.ceil(np.log2(hi - lo))))
                     et = from_raw_batch(_slice_raw(raw, lo, hi), cap)
-                    self.sketch = sketch_update(self.sketch, et,
-                                                use_kernel=self.use_kernel)
+                    self.sketch = sketch_update(self.sketch, et)
         self.ticks_seen += 1
         return records
 
@@ -145,9 +140,7 @@ class QuerySink(_SketchQueries):
     def __init__(self, inner, sketch: Optional[GraphSketch] = None,
                  depth: int = 4, width: int = 256, hh_slots: int = 64,
                  hub=None, answer_every: int = 10, top_k: int = 5,
-                 use_kernel: Optional[bool] = None,
                  incremental: bool = True, exact_topk: int = 0):
-        from repro.kernels import ops
         from repro.query.snapshot import SnapshotMaintainer
         from repro.telemetry.spans import NULL_REGISTRY
 
@@ -158,7 +151,6 @@ class QuerySink(_SketchQueries):
         self.hub = hub
         self.answer_every = max(1, answer_every)
         self.top_k = top_k
-        self.use_kernel = ops.ON_TPU if use_kernel is None else use_kernel
         self.exact_topk = exact_topk
         self.commits = 0
         self._now = None
@@ -187,8 +179,7 @@ class QuerySink(_SketchQueries):
         if self.maintainer is not None:
             self.maintainer.absorb(et, stats)
         with self.telemetry.span("sketch.absorb"):
-            self.sketch = sketch_update(self.sketch, et,
-                                        use_kernel=self.use_kernel)
+            self.sketch = sketch_update(self.sketch, et)
         self.commits += 1
         if self.hub is not None and self.commits % self.answer_every == 0:
             hk, hc = self.heavy_hitters(self.top_k)

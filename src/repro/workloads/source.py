@@ -8,7 +8,7 @@ bottlenecks ingest:
   * tick rates/counts come from `rate_trajectory` one CHUNK of ticks
     at a time (Hawkes state carried across chunks, bit-identical to
     one long chunk),
-  * record ids come from the fused counter-based sampling kernel
+  * record ids come from the fused counter-based sampler
     (`repro.kernels.ops.traffic_sample`) one fixed-size block per
     tick, so shapes are static and the trace compiles once.
 
@@ -25,7 +25,7 @@ Records are tweet-shaped dicts (`id`/`user`/`hashtags`/`mentions`/
 from __future__ import annotations
 
 import collections
-from typing import Iterator, List, Optional, Union
+from typing import Iterator, List, Union
 
 import numpy as np
 
@@ -42,15 +42,13 @@ class ScenarioSource:
 
     def __init__(self, scenario: Union[Scenario, str], seed: int = 0,
                  dt: float = 1.0, block: int = 2048,
-                 rate_scale: float = 1.0, use_kernel: Optional[bool] = None,
-                 recent_window: int = 500):
+                 rate_scale: float = 1.0, recent_window: int = 500):
         self.scenario = (get_scenario(scenario)
                          if isinstance(scenario, str) else scenario)
         self.seed = int(seed)
         self.dt = float(dt)
         self.block = int(block)
         self.rate_scale = float(rate_scale)
-        self.use_kernel = use_kernel
         self.t = 0.0
         self._tick_no = 0
         self._rec_no = 0     # record counter: ids AND PRNG lane base
@@ -65,7 +63,7 @@ class ScenarioSource:
 
     # ------------------------------------------------------------------
     def _sample_ids(self, n: int, burst_level: float):
-        """n record-id tuples from the fused kernel (blocked, padded)."""
+        """n record-id tuples from the fused sampler (blocked, padded)."""
         from repro.kernels import ops
 
         scn = self.scenario
@@ -76,7 +74,7 @@ class ScenarioSource:
             # uint32 counter space wraps for streams past ~500M records
             ctr0 = np.uint32(((self._rec_no + taken) * NSTREAMS) & 0xFFFFFFFF)
             cols = ops.traffic_sample(np.uint32(self.seed), ctr0, self.block,
-                                      ip, fp, use_kernel=self.use_kernel)
+                                      ip, fp)
             k = min(self.block, n - taken)
             out.append([np.asarray(c)[:k] for c in cols])
             taken += k

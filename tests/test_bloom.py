@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 
 from repro.kernels import bloom as B
-from repro.kernels import ops
 
 
 def _np_hash_round(keys: np.ndarray, r: int) -> np.ndarray:
@@ -56,7 +55,7 @@ def _keys(rng, n, hi=10_000):
 def test_build_matches_numpy_oracle(rng, rows, n):
     keys = _keys(rng, n)
     bitmap = B.init_bitmap(rows)
-    built = ops.bloom_build(jnp.asarray(keys), bitmap)
+    built = B.bloom_build(jnp.asarray(keys), bitmap, interpret=True)
     expect = _np_build(keys, np.asarray(bitmap))
     assert (np.asarray(built) == expect).all()
 
@@ -65,8 +64,8 @@ def test_build_matches_numpy_oracle(rng, rows, n):
 def test_probe_matches_numpy_oracle(rng, rows):
     inserted = _keys(rng, 200)
     queries = np.concatenate([inserted[:100], _keys(rng, 100, hi=1 << 30)])
-    bitmap = ops.bloom_build(jnp.asarray(inserted), B.init_bitmap(rows))
-    hits = ops.bloom_probe(jnp.asarray(queries), bitmap)
+    bitmap = B.bloom_build(jnp.asarray(inserted), B.init_bitmap(rows), interpret=True)
+    hits = B.bloom_probe(jnp.asarray(queries), bitmap, interpret=True)
     expect = _np_probe(queries, np.asarray(bitmap))
     assert (np.asarray(hits) == expect).all()
 
@@ -75,8 +74,8 @@ def test_no_false_negatives(rng):
     """The Bloom contract: every inserted key MUST probe as present."""
     for trial in range(5):
         keys = _keys(rng, 256, hi=1 << 31)
-        bitmap = ops.bloom_build(jnp.asarray(keys), B.init_bitmap(8))
-        hits = np.asarray(ops.bloom_probe(jnp.asarray(keys), bitmap))
+        bitmap = B.bloom_build(jnp.asarray(keys), B.init_bitmap(8), interpret=True)
+        hits = np.asarray(B.bloom_probe(jnp.asarray(keys), bitmap, interpret=True))
         assert (hits == 1).all(), f"false negative in trial {trial}"
 
 
@@ -85,37 +84,37 @@ def test_false_positive_rate_bounded(rng):
     positive rate must be far under 1% — a sanity bound, not the exact
     (1-e^{-kn/m})^k formula."""
     inserted = _keys(rng, 512, hi=1 << 20)
-    bitmap = ops.bloom_build(jnp.asarray(inserted), B.init_bitmap(64))
+    bitmap = B.bloom_build(jnp.asarray(inserted), B.init_bitmap(64), interpret=True)
     fresh = (rng.integers(1 << 20, 1 << 30, size=4096)).astype(np.uint32)
-    hits = np.asarray(ops.bloom_probe(jnp.asarray(fresh), bitmap))
+    hits = np.asarray(B.bloom_probe(jnp.asarray(fresh), bitmap, interpret=True))
     assert hits.mean() < 0.01
 
 
 def test_empty_bitmap_probe_all_misses(rng):
     keys = _keys(rng, 128)
-    hits = np.asarray(ops.bloom_probe(jnp.asarray(keys), B.init_bitmap(4)))
+    hits = np.asarray(B.bloom_probe(jnp.asarray(keys), B.init_bitmap(4), interpret=True))
     assert (hits == 0).all()
 
 
 def test_build_idempotent(rng):
     """Re-inserting the same keys cannot change the bitmap."""
     keys = jnp.asarray(_keys(rng, 256))
-    once = ops.bloom_build(keys, B.init_bitmap(8))
-    twice = ops.bloom_build(keys, once)
+    once = B.bloom_build(keys, B.init_bitmap(8), interpret=True)
+    twice = B.bloom_build(keys, once, interpret=True)
     assert jnp.array_equal(once, twice)
 
 
 def test_build_monotone(rng):
     """Building only SETS bits: the old bitmap is a subset of the new."""
-    a = ops.bloom_build(jnp.asarray(_keys(rng, 128)), B.init_bitmap(8))
-    b = ops.bloom_build(jnp.asarray(_keys(rng, 128, hi=1 << 29)), a)
+    a = B.bloom_build(jnp.asarray(_keys(rng, 128)), B.init_bitmap(8), interpret=True)
+    b = B.bloom_build(jnp.asarray(_keys(rng, 128, hi=1 << 29)), a, interpret=True)
     assert jnp.array_equal(jnp.bitwise_and(a, b), a)
 
 
 def test_bloom_diversity_signal(rng):
     """rho = 1 on an all-fresh bucket, 0 on an exact replay."""
     keys = jnp.asarray(_keys(rng, 256, hi=1 << 28))
-    rho_fresh, bitmap = ops.bloom_diversity(keys, B.init_bitmap(32))
+    rho_fresh, bitmap = B.bloom_diversity(keys, B.init_bitmap(32), interpret=True)
     assert float(rho_fresh) == 1.0
-    rho_replay, _ = ops.bloom_diversity(keys, bitmap)
+    rho_replay, _ = B.bloom_diversity(keys, bitmap, interpret=True)
     assert float(rho_replay) == 0.0

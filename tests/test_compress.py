@@ -40,8 +40,8 @@ def _rand_edges(rng, n, cap, n_nodes=20):
 def test_pattern_mine_kernel_matches_oracle(rng, cap, n, pool):
     src, dst, et, valid = _rand_edges(rng, n, cap, n_nodes=pool)
     count = jnp.asarray(rng.integers(1, 4, size=cap).astype(np.int32))
-    a = ops.pattern_mine(src, dst, et, count, valid, 3, 2, use_kernel=True)
-    b = ops.pattern_mine(src, dst, et, count, valid, 3, 2, use_kernel=False)
+    a = PM.pattern_mine(src, dst, et, count, valid, 3, 2, interpret=True)
+    b = ops.pattern_mine(src, dst, et, count, valid, 3, 2)
     for ka, kb, name in zip(a, b, ("fan_out", "fan_in", "flags", "psig")):
         assert jnp.array_equal(ka, kb), f"{name} differs kernel vs oracle"
 
@@ -145,9 +145,7 @@ def test_tree_flatten_preserves_partition_spec_leaves(cls):
 
 
 def test_mix_keys_uint64_bijective_when_ids_fit():
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         rng = np.random.default_rng(7)
         n = 4096
         src = rng.integers(0, 1 << C.PACK_SRC_BITS, n, dtype=np.uint64)
@@ -171,9 +169,7 @@ def test_mix_keys_uint64_bijective_when_ids_fit():
 
 
 def test_mix_keys_uint64_wide_ids_fall_back_to_hash():
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         wide = jnp.asarray(np.asarray([1 << 40, 5], np.uint64))
         dst = jnp.asarray(np.asarray([3, 1 << 50], np.uint64))
         et = jnp.zeros((2,), jnp.int32)

@@ -1,12 +1,15 @@
-"""Per-kernel shape/dtype sweeps, assert_allclose against ref.py oracles
-(kernels run in interpret mode on CPU; same call sites compile on TPU)."""
+"""Per-kernel shape/dtype sweeps, assert_allclose against ref.py oracles.
+
+Kernels run in interpret mode here (`interpret=True`).  Most of them do
+not compile for TPU v5e (ROADMAP S7); tests/test_chip_compile.py
+compiles what the main path runs on the chip."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
-from repro.kernels.edge_dedup import sort_dedup
+from repro.kernels import bloom, ref
+from repro.kernels.edge_dedup import dedup_sorted_counts, sort_dedup
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssd_scan import ssd_scan
 
@@ -30,8 +33,8 @@ def test_sort_dedup_sweep(n, dup_range, rng):
 
 def test_dedup_counts_match_numpy(rng):
     keys = jnp.asarray(rng.integers(0, 37, size=512).astype(np.uint32))
-    sk, order, head = ops.sort_dedup(keys)
-    counts, nu = ops.dedup_sorted_counts(sk, head)
+    sk, order, head = sort_dedup(keys, interpret=True)
+    counts, nu = dedup_sorted_counts(sk, head)
     vals, cts = np.unique(np.asarray(keys), return_counts=True)
     assert int(nu) == len(vals)
     np.testing.assert_array_equal(np.asarray(counts[: len(vals)]), cts)
@@ -46,23 +49,23 @@ def test_dedup_counts_match_numpy(rng):
 def test_bloom_build_matches_ref(n, rows, rng):
     keys = jnp.asarray(rng.integers(1, 2**31, size=n).astype(np.uint32))
     bm = jnp.zeros((rows, 1024), jnp.uint32)
-    out = ops.bloom_build(keys, bm)
+    out = bloom.bloom_build(keys, bm, interpret=True)
     want = ref.bloom_build_ref(np.asarray(keys), np.asarray(bm))
     np.testing.assert_array_equal(np.asarray(out), want)
 
 
 def test_bloom_no_false_negatives(rng):
     keys = jnp.asarray(rng.integers(1, 2**31, size=500).astype(np.uint32))
-    bm = ops.bloom_build(keys, jnp.zeros((16, 1024), jnp.uint32))
-    hit = ops.bloom_probe(keys, bm)
+    bm = bloom.bloom_build(keys, jnp.zeros((16, 1024), jnp.uint32), interpret=True)
+    hit = bloom.bloom_probe(keys, bm, interpret=True)
     assert bool((np.asarray(hit) == 1).all())
 
 
 def test_bloom_low_false_positive_rate(rng):
     seen = jnp.asarray(rng.integers(1, 2**30, size=1000).astype(np.uint32))
-    bm = ops.bloom_build(seen, jnp.zeros((16, 1024), jnp.uint32))
+    bm = bloom.bloom_build(seen, jnp.zeros((16, 1024), jnp.uint32), interpret=True)
     fresh = jnp.asarray((rng.integers(1, 2**30, size=2000) + 2**30).astype(np.uint32))
-    fp = float(np.asarray(ops.bloom_probe(fresh, bm)).mean())
+    fp = float(np.asarray(bloom.bloom_probe(fresh, bm, interpret=True)).mean())
     assert fp < 0.05, fp
 
 

@@ -22,7 +22,7 @@ from repro.query import (
     top_k_degree,
     triangle_count,
 )
-from repro.query.sketch import _merge_top_k, sketch_scatter_ref
+from repro.query.sketch import _merge_top_k, node_hash
 
 
 def _raw(src, dst, etype):
@@ -76,6 +76,7 @@ def _snapshot_edges(snap):
 @pytest.mark.parametrize("depth,width,n", [(2, 128, 64), (4, 128, 256), (3, 256, 512)])
 def test_sketch_kernel_matches_oracle(depth, width, n, rng):
     from repro.kernels import ops
+    from repro.kernels.sketch import sketch_scatter
 
     ew = jnp.asarray(rng.integers(0, 50, size=(depth, width, width)).astype(np.int32))
     od = jnp.asarray(rng.integers(0, 50, size=(depth, width)).astype(np.int32))
@@ -83,21 +84,27 @@ def test_sketch_kernel_matches_oracle(depth, width, n, rng):
     r = jnp.asarray(rng.integers(0, width, size=(depth, n)).astype(np.int32))
     c = jnp.asarray(rng.integers(0, width, size=(depth, n)).astype(np.int32))
     cnt = jnp.asarray(rng.integers(0, 5, size=n).astype(np.int32))
-    got = ops.sketch_scatter(ew, od, idg, r, c, cnt)
-    want = sketch_scatter_ref(ew, od, idg, r, c, cnt)
+    got = sketch_scatter(ew, od, idg, r, c, cnt, interpret=True)
+    want = ops.sketch_scatter(ew, od, idg, r, c, cnt)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 def test_sketch_update_kernel_path_bit_exact(rng):
+    """The Pallas scatter kernel (interpret mode) reproduces the sketch
+    cells `sketch_update` writes through its XLA scatter."""
+    from repro.kernels.sketch import sketch_scatter
+
     _, _, _, tbl = _table(rng)
     sk0 = init_sketch(depth=4, width=128)
-    a = sketch_update(sk0, tbl, use_kernel=False)
-    b = sketch_update(sk0, tbl, use_kernel=True)
-    for f in dataclasses.fields(a):
+    a = sketch_update(sk0, tbl)
+    cnt = jnp.where(tbl.edge_valid, tbl.count, 0).astype(jnp.int32)
+    b = sketch_scatter(sk0.edge_w, sk0.out_deg, sk0.in_deg,
+                       node_hash(tbl.src, 4, 128), node_hash(tbl.dst, 4, 128),
+                       cnt, interpret=True)
+    for name, want in zip(("edge_w", "out_deg", "in_deg"), b):
         np.testing.assert_array_equal(
-            np.asarray(getattr(a, f.name)), np.asarray(getattr(b, f.name)),
-            err_msg=f.name)
+            np.asarray(getattr(a, name)), np.asarray(want), err_msg=name)
 
 
 def test_sketch_upper_bounds_and_tracks_exact(rng):
@@ -156,6 +163,31 @@ def test_merge_top_k_keeps_heaviest():
     got.pop(0, None)
     # 11 deduplicates to its max estimate; top-4 of {10:5, 11:7, 12:9, 13:1}
     assert got == {12: 9, 11: 7, 10: 5, 13: 1}
+
+
+@pytest.mark.parametrize("seed,m", [(0, 4), (1, 64), (2, 256), (3, 1000)])
+def test_merge_top_k_matches_dict_oracle(seed, m):
+    """Duplicate candidates, keys shared with the table, empty slots and
+    tied counts: the merge equals dedup-by-max then the top K ordered by
+    (count desc, key asc), as a plain dict computes it."""
+    r = np.random.default_rng(seed)
+    K = 16
+    hk = r.choice(np.arange(1, 60), size=K, replace=False).astype(np.uint32)
+    hk[r.random(K) < 0.25] = 0
+    hc = np.where(hk != 0, r.integers(0, 6, size=K), 0).astype(np.int32)
+    ck = r.integers(0, 60, size=m).astype(np.uint32)
+    cc = np.where(ck != 0, r.integers(-1, 6, size=m), -1).astype(np.int32)
+    best = {}
+    for k, c in zip(np.concatenate([hk, ck]).tolist(),
+                    np.concatenate([hc, cc]).tolist()):
+        if k != 0:
+            best[k] = max(best.get(k, -1), c)
+    want = [(k, c) for k, c in sorted(best.items(), key=lambda kc: (-kc[1], kc[0]))
+            if c > 0][:K]
+    keys, counts = _merge_top_k(jnp.asarray(hk), jnp.asarray(hc),
+                                jnp.asarray(ck), jnp.asarray(cc))
+    got = list(zip(np.asarray(keys).tolist(), np.asarray(counts).tolist()))
+    assert got == want + [(0, 0)] * (K - len(want))
 
 
 def test_sketch_heavy_hitters_find_hot_nodes(rng):
