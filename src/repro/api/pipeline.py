@@ -46,6 +46,15 @@ def maybe_retry_archive(sink, hub: MetricsHub, now: float) -> int:
     return n
 
 
+def fetch_table_stats(tel, et):
+    """The committed table's compression ratio, size and density as host
+    floats: the loop's device-to-host pulls, read once per tick inside
+    one `loop.fetch` span."""
+    with tel.span("loop.fetch"):
+        return (float(et.compression_ratio()), float(et.size()),
+                float(et.density()))
+
+
 def controlled_tick(buf: BufferControlStage, transform, sink, consumer,
                     hub: MetricsHub, state: dict, now: float, dt: float,
                     consume_dt: Optional[float] = None):
@@ -88,7 +97,7 @@ def controlled_tick(buf: BufferControlStage, transform, sink, consumer,
                 mu = consumer.consume(n_instr, cdt, now=now)
             committed = out.get("committed", False)
             rho = out.get("rho", 1.0) if committed else 1.0
-            cr = float(et.compression_ratio())
+            cr, size, density = fetch_table_stats(tel, et)
             hub.emit("commit" if committed else "commit-failed", now,
                      instructions=n_instr, raw=raw_i, rho=rho, cr=cr,
                      dropped=out.get("dropped", 0),
@@ -111,16 +120,16 @@ def controlled_tick(buf: BufferControlStage, transform, sink, consumer,
             pm.observe_mu(mu)
             if aud is not None:
                 # predicted-vs-realized for the audit trail
-                aud.resolve(mu, float(et.size()))
-            pm.observe_bucket(rho, float(et.density()), float(et.size()))
+                aud.resolve(mu, size)
+            pm.observe_bucket(rho, density, size)
             pm.observe_mu_outcome(state["last_mu"], state["last_beta_e"], mu)
-            state["last_beta_e"], state["last_mu"] = float(et.size()), mu
+            state["last_beta_e"], state["last_mu"] = size, mu
             state["instr"] += n_instr
             state["raw"] += raw_i
             state["crs"].append(cr)
             hub.emit("push", now, records=len(batch))
-            hub.record(PerfSample(now, mu, rho, float(et.density()),
-                                  len(buf), float(et.size()),
+            hub.record(PerfSample(now, mu, rho, density,
+                                  len(buf), size,
                                   *pm.velocity(), dec.action,
                                   buf.spill_depth, cr, consumer.delay_s))
     elif dec.action == "throttle":
@@ -226,7 +235,7 @@ class StreamPipeline:
         mu = self.consumer.consume(n_instr, dt, now=now)
         committed = out.get("committed", False)
         rho = out.get("rho", 1.0) if committed else 1.0
-        cr = float(et.compression_ratio())
+        cr, size, density = fetch_table_stats(self.telemetry, et)
         self.metrics.emit("commit" if committed else "commit-failed", now,
                           instructions=n_instr, raw=raw_instr, rho=rho, cr=cr,
                           dropped=out.get("dropped", 0),
@@ -234,7 +243,7 @@ class StreamPipeline:
                           pressure=out.get("pressure", 0.0),
                           refs=out.get("refs", 0),
                           dict_hit_rate=out.get("dict_hit_rate", 0.0))
-        return et, mu, rho, cr, n_instr, raw_instr
+        return size, density, mu, rho, cr, n_instr, raw_instr
 
     # ------------------------------------------------------------------
     def run(self, source_ticks: Optional[Iterable] = None,
@@ -275,16 +284,15 @@ class StreamPipeline:
                     # paper Figs. 1-3/7: push every tick, no control
                     if len(buf):
                         batch = buf.take_all()
-                        et, mu, rho, cr, ni, ri = self._transform_and_commit(
-                            batch, now, dt)
+                        (size, density, mu, rho, cr, ni,
+                         ri) = self._transform_and_commit(batch, now, dt)
                         pm.observe_mu(mu)
                         state["instr"] += ni
                         state["raw"] += ri
                         state["crs"].append(cr)
                         hub.emit("push", now, records=len(batch))
-                        hub.record(PerfSample(now, mu, rho,
-                                              float(et.density()),
-                                              len(buf), float(et.size()),
+                        hub.record(PerfSample(now, mu, rho, density,
+                                              len(buf), size,
                                               *pm.velocity(), "push",
                                               buf.spill_depth, cr,
                                               self.consumer.delay_s))

@@ -62,6 +62,9 @@ class TransformStage:
             raw = create_edges(records, self.mapping)
         cap = max(64, 1 << int(np.ceil(np.log2(max(raw.n_edges, 1)))))
         cap = min(cap, self.max_edges_per_batch)
+        if raw.n_edges > cap:
+            # `from_raw_batch` keeps the first `cap` raw edges of the tick
+            tel.count("transform.edges_cut", raw.n_edges - cap)
         with tel.span("transform.dedup"):
             et = from_raw_batch(raw, cap)
         raw_instr = 3 * raw.n_edges
@@ -69,7 +72,8 @@ class TransformStage:
             # uncompressed baseline: ingestion load = raw instructions
             n_instr = raw_instr
         else:
-            n_instr = int(et.n_nodes) + int(et.n_edges)
+            with tel.span("transform.fetch"):
+                n_instr = int(et.n_nodes) + int(et.n_edges)
         return et, n_instr, raw_instr
 
 

@@ -343,6 +343,7 @@ class CompressingTransform:
     def encode(self, records: List[dict]) -> Tuple[CompressedCommit, int, int]:
         et, _, raw_instr = self.inner.encode(records)
         cc = self.stage.rewrite(et)
-        n_instr = (int(cc.residual.n_nodes) + int(cc.residual.n_edges)
-                   + int(cc.n_refs))
+        with self.telemetry.span("transform.fetch"):
+            n_instr = (int(cc.residual.n_nodes) + int(cc.residual.n_edges)
+                       + int(cc.n_refs))
         return cc, n_instr, raw_instr

@@ -40,6 +40,11 @@ from repro.graphstore.store import GraphStore, commit_compressed, ingest_step
 from repro.telemetry.spans import NULL_REGISTRY
 
 
+# `ingest_step`'s probe-work stats, fetched together once per commit
+_ROUND_KEYS = ("node_rounds_run", "edge_rounds_run",
+               "node_rounds_needed", "edge_rounds_needed")
+
+
 @dataclasses.dataclass
 class CommitRecord:
     t: float
@@ -48,9 +53,15 @@ class CommitRecord:
     new_nodes: int
     batch_nodes: int
     ok: bool
-    probe_rounds: int = 0  # adaptive probe budget the commit ran with
+    # the probe budget the sweeps ran with: the Algorithm-2 controller's
+    # table-pressure input (not the rounds they did work in; see below)
+    probe_rounds: int = 0
     dropped: int = 0  # inserts lost to table pressure (probing exhausted)
     refs: int = 0  # dictionary pattern references applied (repro.compress)
+    # probe work, summed over the node and the edge sweep: the rounds
+    # their loops executed, and the rounds that placed their last lane
+    rounds_run: int = 0
+    rounds_needed: int = 0
 
 
 def _to_host(et):
@@ -84,7 +95,8 @@ class GraphIngestor:
         self.occupancy_window = occupancy_window
         self._busy: Deque[Tuple[float, float]] = collections.deque(maxlen=512)
         # span telemetry (repro.telemetry): commit milliseconds split
-        # into upsert-dispatch / device-wait / observer-hook sub-spans.
+        # into upsert-dispatch / device-wait / stats-fetch / observer-hook
+        # sub-spans (commit.upsert / .wait / .fetch / .hooks).
         # NULL_REGISTRY = disabled; PipelineBuilder.with_telemetry swaps
         # in the live registry.
         self.telemetry = NULL_REGISTRY
@@ -225,21 +237,29 @@ class GraphIngestor:
                 jax.block_until_ready(new_store.n_nodes)
             self.store = new_store
             busy = time.perf_counter() - t0
-            tel.observe("commit.total", busy)
             self._busy.append((wall, busy))
             self.consecutive_failures = 0
             self.next_retry_t = float("-inf")
-            rec = CommitRecord(
-                t=wall,
-                busy_s=busy,
-                instructions=int(s["instructions"]),
-                new_nodes=int(s["new_nodes"]),
-                batch_nodes=int(s["batch_nodes"]),
-                ok=True,
-                probe_rounds=int(s.get("probe_rounds", 0)),
-                dropped=int(s.get("dropped_inserts", 0)),
-                refs=int(s.get("dict_refs", 0)),
-            )
+            with tel.span("commit.fetch"):
+                nrun, erun, nneed, eneed = jax.device_get(
+                    [s[k] for k in _ROUND_KEYS])
+                rec = CommitRecord(
+                    t=wall,
+                    busy_s=busy,
+                    instructions=int(s["instructions"]),
+                    new_nodes=int(s["new_nodes"]),
+                    batch_nodes=int(s["batch_nodes"]),
+                    ok=True,
+                    probe_rounds=int(s.get("probe_rounds", 0)),
+                    dropped=int(s.get("dropped_inserts", 0)),
+                    refs=int(s.get("dict_refs", 0)),
+                    rounds_run=int(nrun) + int(erun),
+                    rounds_needed=int(nneed) + int(eneed),
+                )
+                pressure = max(float(s.get("node_load", 0.0)),
+                               float(s.get("edge_load", 0.0)))
+                hit_rate = (float(s["dict_hit_rate"])
+                            if "dict_refs" in s else None)
             self.commits.append(rec)
             if self.lineage is not None and tag is not None:
                 # store took it: the committed low watermark may advance
@@ -264,13 +284,12 @@ class GraphIngestor:
                 # table-pressure signals for the Algorithm-2 controller
                 "dropped": rec.dropped,
                 "probe_rounds": rec.probe_rounds,
-                "pressure": max(float(s.get("node_load", 0.0)),
-                                float(s.get("edge_load", 0.0))),
+                "pressure": pressure,
             }
             if "dict_refs" in s:
                 # compressibility signals (repro.compress -> controller)
                 out["refs"] = rec.refs
-                out["dict_hit_rate"] = float(s["dict_hit_rate"])
+                out["dict_hit_rate"] = hit_rate
             return out
         except ConnectionError:
             # commit failed (network/DBMS) -> archive for replay.

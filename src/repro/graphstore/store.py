@@ -136,10 +136,19 @@ def ingest_step(store: GraphStore, et) -> Tuple[GraphStore, dict]:
     node slots via the edge table's dedup index.  Returns (store',
     stats) where stats carries the controller signals: new-node count
     (diversity rho numerator), sizes, the effective instruction count,
-    the table-pressure signals (dropped_inserts, loads, probe budget),
-    and the `CommitDelta` for incremental snapshot maintenance."""
+    the table-pressure signals (dropped_inserts, loads, and
+    `probe_rounds`, the budget the sweeps ran with: the controller's
+    pressure input), and the `CommitDelta` for incremental snapshot
+    maintenance.
+
+    Probe-work counters, one pair per sweep: `node_rounds_run` /
+    `edge_rounds_run`, the rounds the sweep's loop executed (today its
+    budget), and `node_rounds_needed` / `edge_rounds_needed`, the rounds
+    that placed its last lane (`repro.kernels.upsert.rounds_needed`;
+    a dropped lane counts as the budget)."""
     from repro.core.compression import mix_keys
     from repro.kernels import ops
+    from repro.kernels.upsert import rounds_needed
 
     # NB masked lanes scatter to the out-of-range capacity index, which
     # mode="drop" discards; -1 would WRAP to the last slot and corrupt it.
@@ -148,39 +157,55 @@ def ingest_step(store: GraphStore, et) -> Tuple[GraphStore, dict]:
     n_probes_n = probe_budget(store.n_nodes, ncap)
     n_probes_e = probe_budget(store.n_edges, ecap)
 
+    # Each phase runs under a `jax.named_scope` (node_upsert, edge_upsert,
+    # store_scatter, degree_update): the names reach every HLO
+    # instruction's `op_name` metadata, so a trace's device time can be
+    # split by phase.  Scopes are metadata only; the compiled program is
+    # the same without them.
+
     # ---- nodes: MERGE (one fused probe sweep) ----
-    nk, nslot, n_isnew = ops.fused_upsert(
-        store.node_keys, et.node_ids, et.node_valid, n_probes_n)
+    with jax.named_scope("node_upsert"):
+        nk, nslot, n_isnew = ops.fused_upsert(
+            store.node_keys, et.node_ids, et.node_valid, n_probes_n)
     node_placed = et.node_valid & (nslot >= 0)
     is_new = n_isnew & et.node_valid
-    node_count = store.node_count.at[jnp.where(node_placed, nslot, ncap)].add(
-        1, mode="drop"
-    )
+    with jax.named_scope("store_scatter"):
+        node_count = store.node_count.at[
+            jnp.where(node_placed, nslot, ncap)].add(1, mode="drop")
     n_new_nodes = jnp.sum(is_new.astype(jnp.int32))
     dropped_nodes = jnp.sum((et.node_valid & ~node_placed).astype(jnp.int32))
 
     # ---- edges: CREATE-or-count (one fused probe sweep) ----
-    ekey = mix_keys(et.src, et.dst, et.etype)
-    ek, eslot, e_isnew = ops.fused_upsert(
-        store.edge_keys, ekey, et.edge_valid, n_probes_e)
+    with jax.named_scope("store_scatter"):
+        ekey = mix_keys(et.src, et.dst, et.etype)
+    with jax.named_scope("edge_upsert"):
+        ek, eslot, e_isnew = ops.fused_upsert(
+            store.edge_keys, ekey, et.edge_valid, n_probes_e)
     edge_placed = et.edge_valid & (eslot >= 0)
     e_new = e_isnew & et.edge_valid
-    edge_src = store.edge_src.at[jnp.where(e_new, eslot, ecap)].set(et.src, mode="drop")
-    edge_dst = store.edge_dst.at[jnp.where(e_new, eslot, ecap)].set(et.dst, mode="drop")
-    edge_type = store.edge_type.at[jnp.where(e_new, eslot, ecap)].set(et.etype, mode="drop")
-    edge_count = store.edge_count.at[jnp.where(edge_placed, eslot, ecap)].add(
-        et.count, mode="drop")
+    with jax.named_scope("store_scatter"):
+        edge_src = store.edge_src.at[jnp.where(e_new, eslot, ecap)].set(
+            et.src, mode="drop")
+        edge_dst = store.edge_dst.at[jnp.where(e_new, eslot, ecap)].set(
+            et.dst, mode="drop")
+        edge_type = store.edge_type.at[jnp.where(e_new, eslot, ecap)].set(
+            et.etype, mode="drop")
+        edge_count = store.edge_count.at[
+            jnp.where(edge_placed, eslot, ecap)].add(et.count, mode="drop")
     n_new_edges = jnp.sum(e_new.astype(jnp.int32))
     dropped_edges = jnp.sum((et.edge_valid & ~edge_placed).astype(jnp.int32))
 
     # ---- degree update (both endpoints of new edges) — NO re-probing:
     # the dedup index maps each endpoint to its already-upserted slot
-    sslot = nslot[et.src_node_idx]
-    dslot = nslot[et.dst_node_idx]
-    src_deg = e_new & (sslot >= 0)
-    dst_deg = e_new & (dslot >= 0)
-    node_degree = store.node_degree.at[jnp.where(src_deg, sslot, ncap)].add(1, mode="drop")
-    node_degree = node_degree.at[jnp.where(dst_deg, dslot, ncap)].add(1, mode="drop")
+    with jax.named_scope("degree_update"):
+        sslot = nslot[et.src_node_idx]
+        dslot = nslot[et.dst_node_idx]
+        src_deg = e_new & (sslot >= 0)
+        dst_deg = e_new & (dslot >= 0)
+        node_degree = store.node_degree.at[
+            jnp.where(src_deg, sslot, ncap)].add(1, mode="drop")
+        node_degree = node_degree.at[
+            jnp.where(dst_deg, dslot, ncap)].add(1, mode="drop")
 
     new_store = GraphStore(
         node_keys=nk,
@@ -206,7 +231,17 @@ def ingest_step(store: GraphStore, et) -> Tuple[GraphStore, dict]:
         "dropped_nodes": dropped_nodes,
         "dropped_edges": dropped_edges,
         "dropped_inserts": dropped_nodes + dropped_edges,
+        # the budget the sweeps ran with, the controller's pressure input
         "probe_rounds": jnp.maximum(n_probes_n, n_probes_e),
+        # probe work per sweep: the rounds each loop executed, and the
+        # rounds that placed its last lane (upsert.rounds_needed); a
+        # sweep that ends early must keep `*_rounds_run` its true count
+        "node_rounds_run": n_probes_n,
+        "edge_rounds_run": n_probes_e,
+        "node_rounds_needed": rounds_needed(
+            et.node_ids, nslot, et.node_valid, ncap, n_probes_n),
+        "edge_rounds_needed": rounds_needed(
+            ekey, eslot, et.edge_valid, ecap, n_probes_e),
         "node_load": new_store.n_nodes.astype(jnp.float32) / jnp.float32(ncap),
         "edge_load": new_store.n_edges.astype(jnp.float32) / jnp.float32(ecap),
         # per-entry store slots (-1 = dropped): the dictionary-
@@ -258,36 +293,38 @@ def commit_compressed(store: GraphStore, cc) -> Tuple[GraphStore, dict]:
     ncap = store1.node_keys.shape[0]
     ecap = store1.edge_keys.shape[0]
 
-    # ---- reference edges: count accumulation on cached slots ----
-    rv = cc.ref_valid & (cc.ref_eslot >= 0)
-    edge_count = store1.edge_count.at[jnp.where(rv, cc.ref_eslot, ecap)].add(
-        cc.ref_count, mode="drop")
-    n_refs = jnp.sum(rv.astype(jnp.int32))
+    # the residual's phases are named inside `ingest_step`
+    with jax.named_scope("ref_apply"):
+        # ---- reference edges: count accumulation on cached slots ----
+        rv = cc.ref_valid & (cc.ref_eslot >= 0)
+        edge_count = store1.edge_count.at[jnp.where(rv, cc.ref_eslot, ecap)].add(
+            cc.ref_count, mode="drop")
+        n_refs = jnp.sum(rv.astype(jnp.int32))
 
-    # ---- reference-only endpoints: one node_count +1 per unique
-    # batch node, exactly like the raw path ----
-    res_nodes = cc.residual.node_ids  # sorted unique, sentinel tail
-    nn = res_nodes.shape[0]
+        # ---- reference-only endpoints: one node_count +1 per unique
+        # batch node, exactly like the raw path ----
+        res_nodes = cc.residual.node_ids  # sorted unique, sentinel tail
+        nn = res_nodes.shape[0]
 
-    def in_residual(keys):
-        pos = jnp.clip(jnp.searchsorted(res_nodes, keys).astype(jnp.int32),
-                       0, nn - 1)
-        return res_nodes[pos] == keys
+        def in_residual(keys):
+            pos = jnp.clip(jnp.searchsorted(res_nodes, keys).astype(jnp.int32),
+                           0, nn - 1)
+            return res_nodes[pos] == keys
 
-    ref_keys = jnp.concatenate([cc.ref_src, cc.ref_dst])
-    ref_slots = jnp.concatenate([cc.ref_sslot, cc.ref_dslot])
-    cand = (jnp.concatenate([rv, rv]) & (ref_slots >= 0)
-            & ~in_residual(ref_keys))
-    m = ref_keys.shape[0]
-    lane = jnp.arange(m, dtype=jnp.int32)
-    # first occurrence per slot: endpoints shared by several refs (or
-    # by both sides of one) must still count once
-    first = jnp.full((ncap,), m, jnp.int32).at[
-        jnp.where(cand, ref_slots, ncap)].min(lane, mode="drop")
-    nmask = cand & (first[jnp.clip(ref_slots, 0, ncap - 1)] == lane)
-    node_count = store1.node_count.at[jnp.where(nmask, ref_slots, ncap)].add(
-        1, mode="drop")
-    n_ref_nodes = jnp.sum(nmask.astype(jnp.int32))
+        ref_keys = jnp.concatenate([cc.ref_src, cc.ref_dst])
+        ref_slots = jnp.concatenate([cc.ref_sslot, cc.ref_dslot])
+        cand = (jnp.concatenate([rv, rv]) & (ref_slots >= 0)
+                & ~in_residual(ref_keys))
+        m = ref_keys.shape[0]
+        lane = jnp.arange(m, dtype=jnp.int32)
+        # first occurrence per slot: endpoints shared by several refs (or
+        # by both sides of one) must still count once
+        first = jnp.full((ncap,), m, jnp.int32).at[
+            jnp.where(cand, ref_slots, ncap)].min(lane, mode="drop")
+        nmask = cand & (first[jnp.clip(ref_slots, 0, ncap - 1)] == lane)
+        node_count = store1.node_count.at[jnp.where(nmask, ref_slots, ncap)].add(
+            1, mode="drop")
+        n_ref_nodes = jnp.sum(nmask.astype(jnp.int32))
 
     d = s["delta"]
     zb = jnp.zeros_like(rv)
@@ -351,7 +388,9 @@ def count_probe_loops(et) -> int:
 
 # stats keys reduced by max instead of sum across shards (budgets and
 # load factors are per-table properties, not additive counts)
-_STATS_MAX_KEYS = ("probe_rounds", "node_load", "edge_load")
+_STATS_MAX_KEYS = ("probe_rounds", "node_load", "edge_load",
+                   "node_rounds_run", "edge_rounds_run",
+                   "node_rounds_needed", "edge_rounds_needed")
 
 
 def make_distributed_ingest(mesh):

@@ -39,6 +39,25 @@ def probe_hash(keys: jax.Array, cap: int, i: jax.Array) -> jax.Array:
     return ((h.astype(jnp.uint32) + i.astype(jnp.uint32)) % jnp.uint32(cap)).astype(jnp.int32)
 
 
+def rounds_needed(keys: jax.Array, slot: jax.Array, valid: jax.Array,
+                  cap: int, n_probes: jax.Array) -> jax.Array:
+    """Probe rounds a finished sweep needed: the largest over its placed
+    lanes of the round that placed each, as ``((slot - probe_hash(keys,
+    cap, 0)) mod cap) + 1``.  A valid lane that was dropped (``slot`` -1)
+    counts as the whole budget ``n_probes``; 0 when no lane is valid.
+
+    Read from the slots a sweep returned, so the sweep itself is not
+    touched.  A sweep of this many rounds places every lane as the full
+    budget did (`upsert_sweep` resolves a lane at the first round that
+    hits its key or wins an empty slot)."""
+    home = probe_hash(keys, cap, jnp.zeros(keys.shape, jnp.int32))
+    placed = valid & (slot >= 0)
+    rounds = jnp.where(placed, jnp.mod(slot - home, cap) + 1, 0)
+    dropped = jnp.any(valid & (slot < 0))
+    return jnp.maximum(jnp.max(rounds, initial=0),
+                       jnp.where(dropped, jnp.asarray(n_probes, jnp.int32), 0))
+
+
 def upsert_sweep(table_keys: jax.Array, keys: jax.Array, valid: jax.Array,
                  n_probes: jax.Array):
     """Single-pass fused upsert of UNIQUE keys (pre-deduplicated batch).

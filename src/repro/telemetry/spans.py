@@ -19,7 +19,13 @@ Overhead discipline:
   * enabled registry: one ``time.perf_counter_ns`` pair per span, an
     O(1) histogram update, and one bounded event-list append (the
     Chrome-trace timeline; capped at ``max_events``, overflow counted
-    in ``events_dropped`` — never an unbounded list).
+    in ``events_dropped`` — never an unbounded list).  Each span also
+    opens a ``jax.profiler.TraceAnnotation`` named ``repro.<name>`` for
+    its lifetime, so under a profiler trace the program's spans sit on
+    the device trace's clock, nested by time (a span's parent is the
+    span that covers it); with no trace running it records nothing.
+    ``observe`` records an already-measured duration, which no trace
+    can place, so it writes no annotation.
 
 Shard fan-out uses **child registries** (`child(shard)`): a child
 shares the root's histogram/event/audit storage (spans it records are
@@ -32,6 +38,8 @@ from __future__ import annotations
 import collections
 import time
 from typing import Callable, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 NBUCKETS = 64  # bucket i (i>=1) holds durations in [2^(i-1), 2^i) ns
 
@@ -153,11 +161,15 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
+# a span named `x` appears in a profiler trace as `repro.x`
+TRACE_PREFIX = "repro."
+
 
 class Span:
-    """An open timing span; records into the registry on ``__exit__``."""
+    """An open timing span; records into the registry on ``__exit__``,
+    and into a running profiler trace as ``repro.<name>``."""
 
-    __slots__ = ("_reg", "name", "shard", "t0")
+    __slots__ = ("_reg", "name", "shard", "t0", "_ann")
 
     def __init__(self, reg: "TelemetryRegistry", name: str,
                  shard: Optional[int]):
@@ -166,12 +178,15 @@ class Span:
         self.shard = shard
 
     def __enter__(self):
+        self._ann = TraceAnnotation(TRACE_PREFIX + self.name)
+        self._ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        self._reg._finish(self.name, self.shard, self.t0,
-                          time.perf_counter_ns())
+        t1 = time.perf_counter_ns()
+        self._ann.__exit__(None, None, None)
+        self._reg._finish(self.name, self.shard, self.t0, t1)
         return False
 
 

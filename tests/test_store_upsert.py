@@ -24,7 +24,12 @@ from repro.graphstore.store import (
     probe_budget,
 )
 from repro.kernels import ops
-from repro.kernels.upsert import fused_upsert, fused_upsert_ref, probe_hash
+from repro.kernels.upsert import (
+    fused_upsert,
+    fused_upsert_ref,
+    probe_hash,
+    rounds_needed,
+)
 from repro.query.snapshot import (
     SnapshotMaintainer,
     apply_delta,
@@ -167,6 +172,106 @@ def test_high_load_drops_only_when_probing_exhausted():
         assert len(dropped) <= len(dropped_fixed)
 
     check()
+
+
+def _home(key: int, cap: int) -> int:
+    """`probe_hash` round 0 for a uint32 key, in plain Python."""
+    h = (key * 0x9E3779B9) & 0xFFFFFFFF
+    return (h ^ (h >> 16)) % cap
+
+
+def _oracle_rounds(table, keys, budget):
+    """Plain linear probing, one key after another: the round at which
+    each key is found or claims an empty slot, None when its `budget`
+    slots hold other keys."""
+    table, cap, rounds = list(table), len(table), []
+    for k in keys:
+        for r in range(budget):
+            s = (_home(k, cap) + r) % cap
+            if table[s] in (0, k):
+                table[s] = k
+                rounds.append(r + 1)
+                break
+        else:
+            rounds.append(None)
+    return rounds
+
+
+@pytest.mark.parametrize("chains,want", [((1,), 1), ((1, 3), 3),
+                                         ((1, 3, 7), 7),
+                                         ((1, 3, 7, None), 8)])
+def test_rounds_needed_matches_a_linear_probing_oracle(chains, want):
+    """Keys whose probe chains are planted by occupied slots: a chain of
+    c places its key at round c, and `None` is a key whose whole budget
+    of 8 slots is taken, so it is dropped and counts as the budget."""
+    cap, budget = 256, 8
+    pool = iter(range(1, 1 << 20))
+    table, keys, used = [0] * cap, [], set()
+    for c in chains:
+        span = budget if c is None else c - 1
+        while True:  # a key whose chain lies clear of the others'
+            k = next(pool)
+            h = _home(k, cap)
+            window = {(h + r) % cap for r in range(budget + 1)}
+            if not window & used:
+                break
+        used |= window
+        for r in range(span):  # occupy the chain with other keys
+            table[(h + r) % cap] = (1 << 30) + len(used) * 64 + r
+        keys.append(k)
+    rounds = _oracle_rounds(table, keys, budget)
+    assert rounds == list(chains)
+    n = 16  # padded lanes are invalid
+    kv = np.zeros(n, np.uint32)
+    kv[: len(keys)] = keys
+    valid = jnp.arange(n) < len(keys)
+    _, slot, _ = ops.fused_upsert(jnp.asarray(table, jnp.uint32),
+                                  jnp.asarray(kv), valid, jnp.int32(budget))
+    got = rounds_needed(jnp.asarray(kv), slot, valid, cap, jnp.int32(budget))
+    assert int(got) == want == max(r or budget for r in rounds)
+    # no valid lane: nothing needed
+    none = rounds_needed(jnp.asarray(kv), slot, jnp.zeros(n, bool), cap,
+                         jnp.int32(budget))
+    assert int(none) == 0
+
+
+def test_ingest_step_counts_probe_rounds(rng):
+    """The loops run their budget; the rounds needed are the longest
+    linear-probing walk from a key's home to the slot the commit gave
+    it, past slots that hold other keys; the ingestor sums both sweeps
+    into its `CommitRecord`."""
+    from repro.core.ingestor import GraphIngestor
+
+    cap = 1 << 8
+    ing = GraphIngestor(init_store(cap, 1 << 10, key_dtype=jnp.uint32))
+    longest = 0
+    for _ in range(3):  # the store fills, so chains grow
+        et = _table(rng, n=128, n_keys=120, cap=128)
+        et = dataclasses.replace(et, node_ids=et.node_ids.astype(jnp.uint32),
+                                 src=et.src.astype(jnp.uint32),
+                                 dst=et.dst.astype(jnp.uint32))
+        s = ing.push(et)["stats"]
+        assert int(s["node_rounds_run"]) == int(s["edge_rounds_run"]) \
+            == int(s["probe_rounds"]) == MAX_PROBES
+        table = np.asarray(ing.store.node_keys).tolist()
+        walks = []
+        for k, slot in zip(np.asarray(et.node_ids).tolist(),
+                           np.asarray(s["nslot"]).tolist()):
+            if slot < 0:
+                continue
+            r = 0
+            while (_home(k, cap) + r) % cap != slot:
+                assert table[(_home(k, cap) + r) % cap] not in (0, k)
+                r += 1
+            walks.append(r + 1)
+        assert int(s["node_rounds_needed"]) == max(walks)
+        longest = max(longest, max(walks))
+        rec = ing.commits[-1]
+        assert rec.rounds_run == 2 * MAX_PROBES
+        assert rec.rounds_needed == (int(s["node_rounds_needed"])
+                                     + int(s["edge_rounds_needed"]))
+        assert 1 <= int(s["edge_rounds_needed"]) <= MAX_PROBES
+    assert longest > 1  # some chain was walked
 
 
 # ---------------------------------------------------------------------------

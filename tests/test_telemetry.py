@@ -111,7 +111,7 @@ def test_disabled_path_zero_allocation_per_tick():
     for _ in range(200):
         with reg.span("tick"):
             pass
-        reg.observe("commit.total", 1e-6)
+        reg.observe("source.lag", 1e-6)
         reg.count("x")
     after = tracemalloc.take_snapshot().filter_traces(filt)
     tracemalloc.stop()
@@ -360,3 +360,79 @@ def test_snapshot_maintainer_spans():
     m.telemetry = reg
     m.snapshot(init_store(64, 64))
     assert "snapshot.rebuild" in reg.stage_names()
+
+
+# ---------------------------------------------------------------------------
+# spans in the profiler's trace, the device-to-host pulls, the edge cut
+# ---------------------------------------------------------------------------
+
+
+def _tweets(n, start=0):
+    """`n` distinct tweets, four Fig. 6 edges each (one mention, one tag)."""
+    return [{"id": f"t{i}", "user": f"u{i % 97}", "mentions": [f"u{i % 89}"],
+             "hashtags": [f"h{i % 13}"]} for i in range(start, start + n)]
+
+
+def _host_events(log_dir):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(str(log_dir / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    pd = ProfileData.from_file(path)
+    return [e.name for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def test_enabled_spans_reach_the_profiler_trace(tmp_path):
+    import jax
+
+    from repro.api.stages import TransformStage
+
+    reg = TelemetryRegistry()
+    off = TelemetryRegistry(enabled=False)
+    stage = TransformStage(max_edges_per_batch=256, telemetry=reg)
+    with jax.profiler.trace(str(tmp_path)):
+        stage.encode(_tweets(16))
+        assert off.span("quiet") is NULL_SPAN
+        with off.span("quiet"):
+            pass
+    names = _host_events(tmp_path)
+    for span in ("transform.map", "transform.dedup", "transform.fetch"):
+        assert f"repro.{span}" in names
+    assert "repro.quiet" not in names
+    # the registry's own record is unchanged by the annotation
+    assert reg.hist("transform.map").count == 1
+
+
+@pytest.mark.parametrize("uncontrolled", [False, True])
+def test_pipeline_run_records_the_fetch_spans(uncontrolled):
+    reg = TelemetryRegistry()
+    pipe = (PipelineBuilder(IngestConfig(store_nodes=1 << 12,
+                                         store_edges=1 << 13))
+            .with_source(BurstyTweetSource(seed=3, mean_rate=40))
+            .uncontrolled(uncontrolled)
+            .spill_dir(f"/tmp/repro_spill_fetch{uncontrolled}")
+            .with_telemetry(reg)
+            .build())
+    pipe.run(max_ticks=6)
+    commits = reg.hist("commit.wait").count
+    assert commits > 0
+    for span in ("transform.fetch", "commit.fetch", "loop.fetch"):
+        assert reg.hist(span).count == commits, span
+    # `commit.total` duplicated commit.upsert + commit.wait and is gone
+    assert "commit.total" not in reg.stage_names()
+
+
+def test_transform_counts_the_edges_it_cuts():
+    from repro.api.stages import TransformStage
+
+    reg = TelemetryRegistry()
+    stage = TransformStage(max_edges_per_batch=8_192, telemetry=reg)
+    et, _, raw_instr = stage.encode(_tweets(2_100))  # 8,400 raw edges
+    assert raw_instr == 3 * 8_400
+    assert et.src.shape[0] == 8_192
+    assert reg.counters["transform.edges_cut"] == 8_400 - 8_192
+    stage.encode(_tweets(2_048, start=5_000))  # exactly one full table
+    assert reg.counters["transform.edges_cut"] == 8_400 - 8_192
