@@ -65,6 +65,9 @@ class TransformStage:
         if raw.n_edges > cap:
             # `from_raw_batch` keeps the first `cap` raw edges of the tick
             tel.count("transform.edges_cut", raw.n_edges - cap)
+        # lanes of the table that hold no raw edge: how far below full
+        # the tables run
+        tel.count("transform.lanes_padded", cap - min(raw.n_edges, cap))
         with tel.span("transform.dedup"):
             et = from_raw_batch(raw, cap)
         raw_instr = 3 * raw.n_edges

@@ -101,19 +101,18 @@ def build_edge_table(src, dst, etype, valid) -> EdgeTable:
 
 
 def from_raw_batch(raw: RawEdgeBatch, capacity: int) -> EdgeTable:
-    """Host RawEdgeBatch -> padded device arrays -> EdgeTable."""
+    """Host RawEdgeBatch -> padded device arrays -> EdgeTable.
+
+    Keeps the first `capacity` raw edges and pads on the host, so the
+    device sees one shape per capacity whatever the raw edge count."""
     kd = C.key_dtype()
     n = min(raw.n_edges, capacity)
-    pad = capacity - n
 
-    def prep(a, dtype):
-        a = np.asarray(a[:n])
-        return jnp.concatenate(
-            [jnp.asarray(a, dtype), jnp.zeros((pad,), dtype)]
-        )
+    def padded(a, dtype):
+        out = np.zeros(capacity, a.dtype)
+        out[:n] = a[:n]
+        return jnp.asarray(out, dtype)
 
-    src = prep(raw.src, kd)
-    dst = prep(raw.dst, kd)
-    et = prep(raw.etype, jnp.int32)
-    valid = jnp.arange(capacity) < n
-    return build_edge_table(src, dst, et, valid)
+    return build_edge_table(padded(raw.src, kd), padded(raw.dst, kd),
+                            padded(raw.etype, jnp.int32),
+                            jnp.asarray(np.arange(capacity) < n))

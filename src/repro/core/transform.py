@@ -37,15 +37,51 @@ def splitmix64(x: np.ndarray) -> np.ndarray:
     return z
 
 
-def hash_str(type_tag: int, s: str) -> int:
-    """Stable node id for (node_type, key)."""
-    h = np.uint64(1469598103934665603)  # FNV offset
+_FNV_OFFSET = np.uint64(1469598103934665603)
+_FNV_PRIME = np.uint64(1099511628211)
+
+
+def fnv1a(keys: Sequence[str]) -> np.ndarray:
+    """64-bit FNV-1a of each key's UTF-8 bytes, all keys at once.
+
+    The keys are encoded into one byte buffer with explicit offsets and
+    lengths (so a NUL is a byte like any other), and FNV runs column by
+    column: with the keys ordered longest first, the keys that still
+    have a byte at position j are a prefix of that order."""
+    enc = [k.encode("utf-8") for k in keys]
+    n = len(enc)
+    lens = np.fromiter(map(len, enc), np.int64, n)
+    order = np.argsort(-lens, kind="stable")
+    starts = (np.cumsum(lens) - lens)[order]
+    buf = np.frombuffer(b"".join(enc), np.uint8).astype(np.uint64)
+    longest = int(lens.max()) if n else 0
+    # live[j]: how many keys have a byte at position j
+    live = np.searchsorted(-lens[order], -np.arange(longest), side="left")
+    h = np.full(n, _FNV_OFFSET, np.uint64)
     with np.errstate(over="ignore"):
-        for b in s.encode("utf-8"):
-            h = ((h ^ np.uint64(b)) * np.uint64(1099511628211)) & _MASK
-        h ^= np.uint64(type_tag) << np.uint64(56)
-    v = int(splitmix64(np.asarray([h]))[0])
-    return v or 1  # 0 is the empty-slot sentinel
+        for j, m in enumerate(live):
+            h[:m] = (h[:m] ^ buf[starts[:m] + j]) * _FNV_PRIME
+    out = np.empty(n, np.uint64)
+    out[order] = h
+    return out
+
+
+def finalise_ids(fnv: np.ndarray, type_tags: np.ndarray) -> np.ndarray:
+    """Node ids from FNV hashes: the node type XORed into the top byte,
+    then splitmix64; 0 (the empty-slot sentinel) becomes 1."""
+    ids = splitmix64(fnv ^ (np.asarray(type_tags, np.uint64) << np.uint64(56)))
+    ids[ids == 0] = 1
+    return ids
+
+
+def hash_keys(type_tags: np.ndarray, keys: Sequence[str]) -> np.ndarray:
+    """Stable node ids for (node_type, key) pairs, all keys at once."""
+    return finalise_ids(fnv1a(keys), type_tags)
+
+
+def hash_str(type_tag: int, s: str) -> int:
+    """Stable node id for (node_type, key): `hash_keys` of one key."""
+    return int(hash_keys(np.asarray([type_tag]), [s])[0])
 
 
 # ---------------------------------------------------------------------------
@@ -174,28 +210,33 @@ class RawEdgeBatch:
 
 
 def create_edges(records: Sequence[dict], mapping: MappingSpec) -> RawEdgeBatch:
-    """CREATEEDGE over a mini-batch of records.  Linear in #edges."""
-    srcs: List[int] = []
-    dsts: List[int] = []
-    ets: List[int] = []
-    sts: List[int] = []
-    dts: List[int] = []
+    """CREATEEDGE over a mini-batch of records.  Linear in #edges.
+
+    The mapping's extract callables run per record, in order; then one
+    `hash_keys` call turns every endpoint key of the batch into its id."""
+    cut = mapping.max_edges_per_record
+    pairs: List[Tuple[str, str]] = []
+    runs: List[int] = []  # edges each (record, edge def) produced
     for r in records:
         for ed in mapping.edges:
-            pairs = ed.extract(r)
-            if len(pairs) > mapping.max_edges_per_record:
-                pairs = pairs[: mapping.max_edges_per_record]
-            for sk, dk in pairs:
-                srcs.append(hash_str(ed.src_type, str(sk)))
-                dsts.append(hash_str(ed.dst_type, str(dk)))
-                ets.append(ed.etype)
-                sts.append(ed.src_type)
-                dts.append(ed.dst_type)
+            p = ed.extract(r)
+            if len(p) > cut:
+                p = p[:cut]
+            pairs.extend(p)
+            runs.append(len(p))
+    per_def = np.repeat(np.tile(np.arange(len(mapping.edges)), len(records)),
+                        np.asarray(runs, np.int64))
+    col = lambda attr: np.asarray(
+        [getattr(ed, attr) for ed in mapping.edges], np.int32)[per_def]
+    src_type, dst_type = col("src_type"), col("dst_type")
+    keys = [str(sk) for sk, _ in pairs] + [str(dk) for _, dk in pairs]
+    ids = hash_keys(np.concatenate([src_type, dst_type]), keys)
+    n = len(pairs)
     return RawEdgeBatch(
-        src=np.asarray(srcs, np.uint64),
-        dst=np.asarray(dsts, np.uint64),
-        etype=np.asarray(ets, np.int32),
-        src_type=np.asarray(sts, np.int32),
-        dst_type=np.asarray(dts, np.int32),
+        src=ids[:n],
+        dst=ids[n:],
+        etype=col("etype"),
+        src_type=src_type,
+        dst_type=dst_type,
         n_records=len(records),
     )

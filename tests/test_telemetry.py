@@ -436,3 +436,18 @@ def test_transform_counts_the_edges_it_cuts():
     assert reg.counters["transform.edges_cut"] == 8_400 - 8_192
     stage.encode(_tweets(2_048, start=5_000))  # exactly one full table
     assert reg.counters["transform.edges_cut"] == 8_400 - 8_192
+
+
+def test_transform_counts_the_lanes_it_pads():
+    from repro.api.stages import TransformStage
+
+    reg = TelemetryRegistry()
+    stage = TransformStage(max_edges_per_batch=8_192, telemetry=reg)
+    stage.encode(_tweets(2_048))  # one full table
+    assert reg.counters["transform.lanes_padded"] == 0
+    et, _, _ = stage.encode(_tweets(1_000, start=5_000))  # 4,000 edges
+    assert et.src.shape[0] == 4_096
+    assert reg.counters["transform.lanes_padded"] == 96
+    stage.encode(_tweets(2_100, start=9_000))  # cut: no lane padded
+    stage.encode(_tweets(5, start=20_000))  # 20 edges in 64 lanes
+    assert reg.counters["transform.lanes_padded"] == 96 + 44
