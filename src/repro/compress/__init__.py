@@ -3,12 +3,13 @@
 Cross-batch counterpart of the Algorithm-1 within-batch dedup: a
 device-resident dictionary of frequently recurring edges (members of
 mined star-burst / cascade-chain / hot-edge patterns) lets the
-pipeline rewrite each batch into compact pattern *references* plus a
-residual raw-edge tail.  References commit by direct scatter to their
-cached store slots — zero probe rounds — so the redundant portion of a
-bursty stream stops paying the hash-table toll every batch (GraphZip,
-Packer & Holder, arXiv:1703.08614; the ROADMAP "ingestion-time
-dictionary compression" item).
+pipeline rewrite each batch into pattern *references* plus a residual,
+both masks over the batch table's lanes, so the path's programs depend
+on the table's capacity alone.  References commit by direct scatter to
+their cached store slots, with no probe of their own; the residual's
+sweeps still run over all of the table's lanes (GraphZip, Packer &
+Holder, arXiv:1703.08614; the ROADMAP "ingestion-time dictionary
+compression" item).
 
     pipe = (PipelineBuilder(cfg)
             .with_source(src)

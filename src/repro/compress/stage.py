@@ -1,27 +1,37 @@
 """Dictionary-compression pipeline stages (GraphZip rewrite path).
 
 `DictionaryStage.rewrite` turns one dedup'd `EdgeTable` into a
-`CompressedCommit`: the batch's dictionary hits become `(pattern_id,
-bindings)` *references* — the binding is the cached (edge, src, dst)
-store-slot triple — and the misses become a smaller residual
-`EdgeTable` that takes the normal two-sweep commit.  Mining
-(`repro.kernels.pattern_mine`) marks which residual edges belong to
-frequent patterns; after the store confirms their slots,
-`observe_commit` admits them to the dictionary so the NEXT occurrence
-is a reference.
+`CompressedCommit` in one jitted program per table capacity: the
+batch's dictionary hits become *references* — the dictionary entry
+(the pattern id) and its cached store edge slot (the binding) — and
+the misses the *residual*.  Both are masks over the table's own lanes,
+not compacted copies: the residual is the table with `edge_valid &
+~hit`, keeping the table's node arrays, and the references are the
+same lanes with `hit`.  So every program of the path has the table's
+shapes, whatever share of the table hits, and nothing is pulled to the
+host to choose a shape.  Mining (`repro.kernels.pattern_mine`) marks
+which residual edges belong to frequent patterns; after the store
+confirms their slots, `observe_commit` admits them to the dictionary
+so the NEXT occurrence is a reference.
 
 Bit-exactness: an edge's first-ever appearance is always a dictionary
 miss (the dictionary only holds previously committed edges), so it is
 inserted by the residual sweep exactly as the raw path would; present
 keys never claim empty slots in `upsert_sweep`, so the scatter races
 involve the same new-key set in both paths and every placement/count
-lands identically — `tests/test_compress.py` asserts full store
-equality against the uncompressed path.
+lands identically.  The residual keeps every node of the table, so the
+node sweep counts each unique batch node once, reference-only
+endpoints included, as the raw path does — `tests/test_compress.py`
+asserts full store equality against the uncompressed path.
+
+The price of the fixed shape: the residual's sweep runs over all of
+the table's lanes, and the counter `rewrite.lanes_idle` counts the
+lanes of the residual and of the references that hold no edge.
 
 `CompressedCommit` duck-types the `EdgeTable` surface the rest of the
-system reads (`controlled_tick` metadata, `sketch_update` fields), so
-sinks, sketches and the snapshot maintainer observe compressed commits
-unchanged.
+system reads (`controlled_tick` metadata, `sketch_update` fields) at
+the table's own shapes, so sinks, sketches and the snapshot maintainer
+observe compressed commits unchanged.
 """
 from __future__ import annotations
 
@@ -31,9 +41,8 @@ from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro.core.compression import dedup_with_counts, mix_keys
+from repro.core.compression import mix_keys
 from repro.core.edge_table import EdgeTable
 from repro.compress.dictionary import (
     PatternDictionary,
@@ -42,162 +51,153 @@ from repro.compress.dictionary import (
     init_dictionary,
 )
 
-REF_MIN_CAP = 8  # smallest static reference-array capacity
-
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class CompressedCommit:
-    """One batch rewritten as residual EdgeTable + pattern references.
+    """One batch rewritten as residual + pattern references, both masks
+    over the batch table's lanes.
 
-    Reference arrays are (R,) at a static power-of-two capacity;
-    `ref_eslot`/`ref_sslot`/`ref_dslot` are the dictionary's cached
-    store slots (the reference bindings), `ref_pattern` the dictionary
-    entry index (the pattern id).  Scalar metadata keeps the FULL
-    batch's unique node/edge counts so controller signals (density,
-    size, rho denominator) match the uncompressed path.
+    `residual` is the table with its hit lanes made invalid; the
+    reference arrays are (cap,) over the same lanes: `ref_valid` marks
+    the hits, `ref_eslot` is the dictionary's cached store edge slot
+    (the binding) and `ref_pattern` the dictionary entry (the pattern
+    id), -1 off the hits.  A reference's edge and count are the
+    residual's arrays at its lane.  Scalar metadata keeps the FULL
+    batch's unique edge count so controller signals (density, size,
+    rho denominator) match the uncompressed path.
     """
 
     residual: EdgeTable
-    res_admit: jax.Array    # (rcap,) bool — mined pattern members to admit
-    res_psig: jax.Array     # (rcap,) key dtype — their pattern signatures
-    ref_src: jax.Array      # (R,) key dtype
-    ref_dst: jax.Array      # (R,) key dtype
-    ref_etype: jax.Array    # (R,) int32
-    ref_count: jax.Array    # (R,) int32 batch multiplicity
-    ref_eslot: jax.Array    # (R,) int32 store edge slot (binding)
-    ref_sslot: jax.Array    # (R,) int32 store src-node slot
-    ref_dslot: jax.Array    # (R,) int32 store dst-node slot
-    ref_pattern: jax.Array  # (R,) int32 dictionary entry (pattern id)
-    ref_valid: jax.Array    # (R,) bool
+    res_admit: jax.Array    # (cap,) bool — mined pattern members to admit
+    res_psig: jax.Array     # (cap,) key dtype — their pattern signatures
+    ref_valid: jax.Array    # (cap,) bool dictionary hits
+    ref_eslot: jax.Array    # (cap,) int32 store edge slot (binding)
+    ref_pattern: jax.Array  # (cap,) int32 dictionary entry (pattern id)
     n_refs: jax.Array       # scalar int32
+    n_instr: jax.Array      # scalar int32 instructions: residual nodes
+    #                         and edges, one per reference
     n_raw: jax.Array        # scalar int32 full-batch raw instructions
-    n_nodes_full: jax.Array  # scalar int32 full-batch unique nodes
     n_edges_full: jax.Array  # scalar int32 full-batch unique edges
 
     def tree_flatten(self):
-        return (self.residual, self.res_admit, self.res_psig, self.ref_src,
-                self.ref_dst, self.ref_etype, self.ref_count, self.ref_eslot,
-                self.ref_sslot, self.ref_dslot, self.ref_pattern,
-                self.ref_valid, self.n_refs, self.n_raw, self.n_nodes_full,
-                self.n_edges_full), None
+        return (self.residual, self.res_admit, self.res_psig, self.ref_valid,
+                self.ref_eslot, self.ref_pattern, self.n_refs, self.n_instr,
+                self.n_raw, self.n_edges_full), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         return cls(*children)
 
+    @property
+    def lanes(self) -> int:
+        """Lanes of the residual and of the references."""
+        return self.residual.src.shape[0] + self.ref_valid.shape[0]
+
     # ---- EdgeTable duck-type surface (sketch_update reads these) ----
     @property
     def src(self):
-        return jnp.concatenate([self.residual.src, self.ref_src])
+        return self.residual.src
 
     @property
     def dst(self):
-        return jnp.concatenate([self.residual.dst, self.ref_dst])
+        return self.residual.dst
 
     @property
     def etype(self):
-        return jnp.concatenate([self.residual.etype, self.ref_etype])
+        return self.residual.etype
 
     @property
     def count(self):
-        return jnp.concatenate([self.residual.count, self.ref_count])
+        return self.residual.count
 
     @property
     def edge_valid(self):
-        return jnp.concatenate([self.residual.edge_valid, self.ref_valid])
+        return self.residual.edge_valid | self.ref_valid
 
     @property
     def node_ids(self):
-        return jnp.concatenate([
-            self.residual.node_ids,
-            jnp.where(self.ref_valid, self.ref_src, 0),
-            jnp.where(self.ref_valid, self.ref_dst, 0)])
+        return self.residual.node_ids
 
     @property
     def node_valid(self):
-        return jnp.concatenate([self.residual.node_valid,
-                                self.ref_valid, self.ref_valid])
+        return self.residual.node_valid
 
     # ---- table-level metadata (controlled_tick reads these) ----
     def density(self) -> jax.Array:
-        v = jnp.maximum(self.n_nodes_full.astype(jnp.float32), 2.0)
+        v = jnp.maximum(self.residual.n_nodes.astype(jnp.float32), 2.0)
         return 2.0 * self.n_edges_full.astype(jnp.float32) / (v * (v - 1.0))
 
     def size(self) -> jax.Array:
-        return self.n_edges_full + self.n_nodes_full
+        return self.n_edges_full + self.residual.n_nodes
 
     def compression_ratio(self) -> jax.Array:
         """Fig. 13 accounting with references: a reference costs ONE
         instruction (vs 1 edge + up to 2 node instructions raw)."""
-        eff = (self.residual.n_nodes + self.residual.n_edges
-               + self.n_refs).astype(jnp.float32)
         raw = jnp.maximum((3 * self.n_raw).astype(jnp.float32), 1.0)
-        return eff / raw
+        return self.n_instr.astype(jnp.float32) / raw
 
 
-def _empty_refs(kd, cap: int = REF_MIN_CAP):
-    return dict(
-        ref_src=jnp.zeros((cap,), kd), ref_dst=jnp.zeros((cap,), kd),
-        ref_etype=jnp.zeros((cap,), jnp.int32),
-        ref_count=jnp.zeros((cap,), jnp.int32),
-        ref_eslot=jnp.full((cap,), -1, jnp.int32),
-        ref_sslot=jnp.full((cap,), -1, jnp.int32),
-        ref_dslot=jnp.full((cap,), -1, jnp.int32),
-        ref_pattern=jnp.full((cap,), -1, jnp.int32),
-        ref_valid=jnp.zeros((cap,), bool),
-        n_refs=jnp.zeros((), jnp.int32),
-    )
+@partial(jax.jit, static_argnames=("star_min", "hot_min"))
+def rewrite_table(d: PatternDictionary, et: EdgeTable, star_min: int,
+                  hot_min: int) -> Tuple[PatternDictionary, CompressedCommit]:
+    """Mine, look up and mask one dedup'd table: the dictionary after
+    the lookup, and the table as a `CompressedCommit`.  Every output has
+    the table's shapes."""
+    from repro.kernels import ops
+
+    with jax.named_scope("rewrite_mine"):
+        _, _, flags, psig = ops.pattern_mine(
+            et.src, et.dst, et.etype, et.count, et.edge_valid,
+            star_min, hot_min)
+    with jax.named_scope("rewrite_lookup"):
+        keys = mix_keys(et.src, et.dst, et.etype)
+        d, hit, eslot, _, _, entry = dict_lookup(d, keys, et.edge_valid)
+    with jax.named_scope("rewrite_mask"):
+        keep = et.edge_valid & ~hit
+        n_edges = jnp.sum(keep.astype(jnp.int32))
+        n_refs = jnp.sum(hit.astype(jnp.int32))
+        # the residual's own endpoints, for the instruction count: the
+        # residual keeps every node of the table for the node sweep
+        nn = et.node_ids.shape[0]
+        used = jnp.zeros((nn,), bool)
+        used = used.at[jnp.where(keep, et.src_node_idx, nn)].set(
+            True, mode="drop")
+        used = used.at[jnp.where(keep, et.dst_node_idx, nn)].set(
+            True, mode="drop")
+        n_res_nodes = jnp.sum((used & et.node_valid).astype(jnp.int32))
+        residual = dataclasses.replace(
+            et, edge_valid=keep, n_edges=n_edges,
+            n_raw=jnp.sum(jnp.where(keep, et.count, 0)))
+        cc = CompressedCommit(
+            residual=residual,
+            res_admit=(flags != 0) & keep,
+            res_psig=jnp.where(keep, psig, 0),
+            ref_valid=hit,
+            ref_eslot=jnp.where(hit, eslot, -1),
+            ref_pattern=jnp.where(hit, entry, -1),
+            n_refs=n_refs,
+            n_instr=n_res_nodes + n_edges + n_refs,
+            n_raw=et.n_raw,
+            n_edges_full=et.n_edges,
+        )
+    return d, cc
 
 
-@partial(jax.jit, static_argnames=("rcap", "refcap"))
-def _split(et: EdgeTable, hit, admit, psig, eslot, sslot, dslot, entry,
-           rcap: int, refcap: int) -> CompressedCommit:
-    """Compact dictionary hits into reference arrays and misses into a
-    residual EdgeTable (static power-of-two capacities)."""
-    keep = et.edge_valid & ~hit
-    order = jnp.argsort(~keep)  # stable: kept edges first, in order
-    sidx = order[:rcap]
-    rvalid = keep[sidx]
-    zed = lambda a: jnp.where(rvalid, a[sidx], 0)
-    rsrc, rdst = zed(et.src), zed(et.dst)
-    rety, rcnt = zed(et.etype), zed(et.count)
-    ncomp = dedup_with_counts(jnp.concatenate([rsrc, rdst]),
-                              jnp.concatenate([rvalid, rvalid]))
-    nidx = lambda k: jnp.clip(
-        jnp.searchsorted(ncomp.keys, k).astype(jnp.int32), 0, 2 * rcap - 1)
-    residual = EdgeTable(
-        src=rsrc, dst=rdst, etype=rety, count=rcnt, edge_valid=rvalid,
-        node_ids=ncomp.keys, node_valid=ncomp.valid,
-        src_node_idx=nidx(rsrc), dst_node_idx=nidx(rdst),
-        n_edges=jnp.sum(rvalid.astype(jnp.int32)),
-        n_nodes=ncomp.n_unique,
-        n_raw=jnp.sum(jnp.where(rvalid, rcnt, 0)),
-    )
-    rorder = jnp.argsort(~hit)
-    ridx = rorder[:refcap]
-    refv = hit[ridx]
-    gk = lambda a: jnp.where(refv, a[ridx], 0)
-    gi = lambda a: jnp.where(refv, a[ridx], -1)
-    return CompressedCommit(
-        residual=residual,
-        res_admit=admit[sidx] & rvalid,
-        res_psig=jnp.where(rvalid, psig[sidx], 0),
-        ref_src=gk(et.src), ref_dst=gk(et.dst),
-        ref_etype=jnp.where(refv, et.etype[ridx], 0),
-        ref_count=jnp.where(refv, et.count[ridx], 0),
-        ref_eslot=gi(eslot), ref_sslot=gi(sslot), ref_dslot=gi(dslot),
-        ref_pattern=gi(entry),
-        ref_valid=refv,
-        n_refs=jnp.sum(refv.astype(jnp.int32)),
-        n_raw=et.n_raw,
-        n_nodes_full=et.n_nodes,
-        n_edges_full=et.n_edges,
-    )
-
-
-def _pow2(n: int, lo: int) -> int:
-    return max(lo, 1 << int(np.ceil(np.log2(max(n, 1)))))
+@partial(jax.jit, static_argnames=("ttl",))
+def admit_committed(d: PatternDictionary, cc: CompressedCommit,
+                    eslot: jax.Array, nslot: jax.Array,
+                    ttl: int) -> PatternDictionary:
+    """Admit a committed batch's mined pattern members with the slots
+    the commit confirmed (`eslot` per edge lane, `nslot` per node lane;
+    -1 where not placed)."""
+    res = cc.residual
+    sslot = nslot[res.src_node_idx]
+    dslot = nslot[res.dst_node_idx]
+    admit = cc.res_admit & (eslot >= 0) & (sslot >= 0) & (dslot >= 0)
+    keys = mix_keys(res.src, res.dst, res.etype)
+    return dict_admit(d, keys, admit, eslot, sslot, dslot, cc.res_psig,
+                      ttl=ttl)
 
 
 class DictionaryStage:
@@ -247,60 +247,33 @@ class DictionaryStage:
             self.dct = init_dictionary(self.capacity, kd)
 
     def rewrite(self, et: EdgeTable) -> CompressedCommit:
-        """Mine + dictionary lookup + split one dedup'd batch."""
-        from repro.kernels import ops
-
-        kd = et.src.dtype
-        tel = self.telemetry
-        self._ensure(kd)
-        with tel.span("rewrite.mine"):
-            fan_out, fan_in, flags, psig = ops.pattern_mine(
-                et.src, et.dst, et.etype, et.count, et.edge_valid,
-                self.star_min, self.hot_min)
-        with tel.span("rewrite.lookup"):
-            keys = mix_keys(et.src, et.dst, et.etype)
-            self.dct, hit, eslot, sslot, dslot, entry = dict_lookup(
-                self.dct, keys, et.edge_valid)
-            n_ref = int(jnp.sum(hit.astype(jnp.int32)))
-        admit = (flags != 0) & et.edge_valid & ~hit
+        """Mine + dictionary lookup + mask one dedup'd batch: one
+        program per table capacity, no device-to-host pull."""
+        self._ensure(et.src.dtype)
+        with self.telemetry.span("rewrite.table"):
+            self.dct, cc = rewrite_table(self.dct, et, self.star_min,
+                                         self.hot_min)
         self.rewrites += 1
-        self.refs_total += n_ref
-        if n_ref == 0:
-            # nothing referenced: the batch IS the residual
-            return CompressedCommit(
-                residual=et, res_admit=admit,
-                res_psig=jnp.where(et.edge_valid, psig, 0),
-                n_raw=et.n_raw, n_nodes_full=et.n_nodes,
-                n_edges_full=et.n_edges, **_empty_refs(kd))
-        cap = et.src.shape[0]
-        n_valid = int(jnp.sum(et.edge_valid.astype(jnp.int32)))
-        rcap = min(_pow2(max(n_valid - n_ref, 1), 64), cap)
-        refcap = min(_pow2(n_ref, REF_MIN_CAP), cap)
-        with tel.span("rewrite.split"):
-            return _split(et, hit, admit, psig, eslot, sslot, dslot, entry,
-                          rcap, refcap)
+        return cc
 
     # ---- commit feedback (ingestor.commit_hooks) ----
     def observe_commit(self, committed, stats) -> None:
-        """Admit the just-committed batch's mined pattern members using
-        the slots the commit confirmed (`nslot`/`eslot` commit stats)."""
+        """Count the commit's references (`dict_refs`, which the
+        ingestor has already pulled) and admit the just-committed
+        batch's mined pattern members using the slots the commit
+        confirmed (`nslot`/`eslot` commit stats)."""
         if self.dct is None or stats is None:
             return
-        res = getattr(committed, "residual", None)
-        admit_mask = getattr(committed, "res_admit", None)
-        if res is None or admit_mask is None:
+        if getattr(committed, "res_admit", None) is None:
             return
         eslot = stats.get("eslot")
         nslot = stats.get("nslot")
         if eslot is None or nslot is None:
             return
+        self.refs_total += int(stats["dict_refs"])
         with self.telemetry.span("dict.admit"):
-            sslot = nslot[res.src_node_idx]
-            dslot = nslot[res.dst_node_idx]
-            admit = admit_mask & (eslot >= 0) & (sslot >= 0) & (dslot >= 0)
-            keys = mix_keys(res.src, res.dst, res.etype)
-            self.dct = dict_admit(self.dct, keys, admit, eslot, sslot, dslot,
-                                  committed.res_psig, ttl=self.ttl)
+            self.dct = admit_committed(self.dct, committed, eslot, nslot,
+                                       self.ttl)
 
     # ---- observability ----
     def stats(self) -> dict:
@@ -343,7 +316,11 @@ class CompressingTransform:
     def encode(self, records: List[dict]) -> Tuple[CompressedCommit, int, int]:
         et, _, raw_instr = self.inner.encode(records)
         cc = self.stage.rewrite(et)
-        with self.telemetry.span("transform.fetch"):
-            n_instr = (int(cc.residual.n_nodes) + int(cc.residual.n_edges)
-                       + int(cc.n_refs))
-        return cc, n_instr, raw_instr
+        tel = self.telemetry
+        with tel.span("transform.fetch"):
+            n_instr, edges = jax.device_get((cc.n_instr, cc.n_edges_full))
+        # every valid edge holds one lane, of the residual or of the
+        # references; the rest of their lanes is the fixed shape's price
+        tel.count("rewrite.edges", int(edges))
+        tel.count("rewrite.lanes_idle", cc.lanes - int(edges))
+        return cc, int(n_instr), raw_instr

@@ -62,6 +62,9 @@ class CommitRecord:
     # their loops executed, and the rounds that placed their last lane
     rounds_run: int = 0
     rounds_needed: int = 0
+    # lanes of a rewritten table's residual and references that held no
+    # edge (repro.compress; 0 for a table that was not rewritten)
+    lanes_idle: int = 0
 
 
 def _to_host(et):
@@ -256,6 +259,10 @@ class GraphIngestor:
                     rounds_run=int(nrun) + int(erun),
                     rounds_needed=int(nneed) + int(eneed),
                 )
+                if compressed:
+                    # every edge of the table holds one of these lanes
+                    rec.lanes_idle = et.lanes - (rec.instructions
+                                                 - rec.new_nodes)
                 pressure = max(float(s.get("node_load", 0.0)),
                                float(s.get("edge_load", 0.0)))
                 hit_rate = (float(s["dict_hit_rate"])

@@ -278,19 +278,21 @@ def commit_compressed(store: GraphStore, cc) -> Tuple[GraphStore, dict]:
     are by construction already present (their slots were cached at a
     previous successful commit and slots are never freed), so the
     result is bit-identical to committing the full raw batch: counts
-    accumulate on the same slots, no degrees change (refs are never
-    new edges), and each unique batch node still gets exactly one
-    `node_count` increment (reference-only endpoints are counted here,
-    deduplicated against the residual's node set).
+    accumulate on the same slots and no degrees change (refs are never
+    new edges).  The residual keeps every node of the batch table, so
+    its node sweep gives each unique batch node, reference-only
+    endpoints included, exactly one `node_count` increment.  The
+    residual and the references are masks over the table's lanes, so
+    this compiles once per table capacity.
 
     Stats keep the raw-path keys with FULL-batch semantics (so rho,
     instruction accounting and pressure signals stay comparable) plus
-    `dict_refs` / `dict_hit_rate`, and the `CommitDelta` carries the
-    reference edges as placed-not-new entries so incremental snapshots
-    (repro.query.snapshot.apply_delta) stay exact.
+    `dict_refs` / `dict_hit_rate`, and the `CommitDelta` marks the
+    reference lanes placed-not-new, as the raw path's delta has them,
+    so incremental snapshots (repro.query.snapshot.apply_delta) stay
+    exact.
     """
     store1, s = ingest_step(store, cc.residual)
-    ncap = store1.node_keys.shape[0]
     ecap = store1.edge_keys.shape[0]
 
     # the residual's phases are named inside `ingest_step`
@@ -298,63 +300,21 @@ def commit_compressed(store: GraphStore, cc) -> Tuple[GraphStore, dict]:
         # ---- reference edges: count accumulation on cached slots ----
         rv = cc.ref_valid & (cc.ref_eslot >= 0)
         edge_count = store1.edge_count.at[jnp.where(rv, cc.ref_eslot, ecap)].add(
-            cc.ref_count, mode="drop")
+            cc.residual.count, mode="drop")
         n_refs = jnp.sum(rv.astype(jnp.int32))
 
-        # ---- reference-only endpoints: one node_count +1 per unique
-        # batch node, exactly like the raw path ----
-        res_nodes = cc.residual.node_ids  # sorted unique, sentinel tail
-        nn = res_nodes.shape[0]
-
-        def in_residual(keys):
-            pos = jnp.clip(jnp.searchsorted(res_nodes, keys).astype(jnp.int32),
-                           0, nn - 1)
-            return res_nodes[pos] == keys
-
-        ref_keys = jnp.concatenate([cc.ref_src, cc.ref_dst])
-        ref_slots = jnp.concatenate([cc.ref_sslot, cc.ref_dslot])
-        cand = (jnp.concatenate([rv, rv]) & (ref_slots >= 0)
-                & ~in_residual(ref_keys))
-        m = ref_keys.shape[0]
-        lane = jnp.arange(m, dtype=jnp.int32)
-        # first occurrence per slot: endpoints shared by several refs (or
-        # by both sides of one) must still count once
-        first = jnp.full((ncap,), m, jnp.int32).at[
-            jnp.where(cand, ref_slots, ncap)].min(lane, mode="drop")
-        nmask = cand & (first[jnp.clip(ref_slots, 0, ncap - 1)] == lane)
-        node_count = store1.node_count.at[jnp.where(nmask, ref_slots, ncap)].add(
-            1, mode="drop")
-        n_ref_nodes = jnp.sum(nmask.astype(jnp.int32))
-
     d = s["delta"]
-    zb = jnp.zeros_like(rv)
-    comb = CommitDelta(
-        node_ids=jnp.concatenate([d.node_ids, ref_keys]),
-        node_placed=jnp.concatenate([d.node_placed, nmask]),
-        node_new=jnp.concatenate([d.node_new, jnp.zeros_like(nmask)]),
-        src=jnp.concatenate([d.src, cc.ref_src]),
-        dst=jnp.concatenate([d.dst, cc.ref_dst]),
-        etype=jnp.concatenate([d.etype, cc.ref_etype]),
-        count=jnp.concatenate([d.count, cc.ref_count]),
-        edge_placed=jnp.concatenate([d.edge_placed, rv]),
-        edge_new=jnp.concatenate([d.edge_new, zb]),
-        src_deg=jnp.concatenate([d.src_deg, zb]),
-        dst_deg=jnp.concatenate([d.dst_deg, zb]),
-    )
-
     batch_edges = s["batch_edges"] + n_refs
     stats = dict(s)
     stats.update(
-        batch_nodes=s["batch_nodes"] + n_ref_nodes,
         batch_edges=batch_edges,
         instructions=s["new_nodes"] + batch_edges,
         dict_refs=n_refs,
         dict_hit_rate=(n_refs.astype(jnp.float32)
                        / jnp.maximum(batch_edges.astype(jnp.float32), 1.0)),
-        delta=comb,
+        delta=dataclasses.replace(d, edge_placed=d.edge_placed | rv),
     )
-    new_store = dataclasses.replace(
-        store1, edge_count=edge_count, node_count=node_count)
+    new_store = dataclasses.replace(store1, edge_count=edge_count)
     return new_store, stats
 
 

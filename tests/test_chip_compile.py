@@ -19,7 +19,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.compress.stage import _split
+from repro.compress.dictionary import init_dictionary
+from repro.compress.stage import rewrite_table
 from repro.configs.paper_ingest import IngestConfig
 from repro.core.edge_table import build_edge_table
 from repro.graphstore.store import commit_compressed, ingest_step, init_store
@@ -102,10 +103,10 @@ def test_ingest_step_compiles_for_v5e(one_chip, x64):
 
 
 def test_commit_compressed_compiles_for_v5e(one_chip, x64):
-    mask, slot = _vec(jnp.bool_), _vec(jnp.int32)
-    cc = jax.eval_shape(functools.partial(_split, rcap=N, refcap=N),
-                        _edge_table(), mask, mask, _vec(jnp.uint64),
-                        slot, slot, slot, slot)
+    dct = jax.eval_shape(lambda: init_dictionary(4096, jnp.uint64))
+    _, cc = jax.eval_shape(
+        functools.partial(rewrite_table, star_min=4, hot_min=2),
+        dct, _edge_table())
     _compile(one_chip, commit_compressed, _store(), cc)
 
 

@@ -7,6 +7,7 @@ therefore byte-identical snapshots).  See the lemma in
 repro/compress/stage.py's module docstring.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -290,6 +291,113 @@ def test_commit_compressed_bit_exact_store_and_snapshot(rng):
     for f in dataclasses.fields(snap_a):
         a, b = getattr(snap_a, f.name), getattr(snap_b, f.name)
         assert jnp.array_equal(a, b), f"snapshot field {f.name} diverged"
+
+
+def _distinct_edges(rng, n, lo, cap):
+    """Raw lanes of `n` edges between fresh ids above `lo` (sources and
+    targets apart, so the raw and the dictionary path see the same
+    nodes), padded to `cap`."""
+    src = rng.integers(lo + 1, lo + 4 * cap, size=cap).astype(np.uint32)
+    dst = rng.integers(lo + 4 * cap, lo + 8 * cap, size=cap).astype(np.uint32)
+    ety = rng.integers(1, 4, size=cap).astype(np.int32)
+    return src, dst, ety, np.arange(cap) < n
+
+
+def _tables(rng, cap, share):
+    """Two tables of one capacity: a cold one, then one whose edges are
+    none, half or all of the first's."""
+    n = 3 * cap // 4
+    a = _distinct_edges(rng, n, 0, cap)
+    fresh = _distinct_edges(rng, n, 8 * cap, cap)
+    keep = {"none": 0, "partial": n // 2, "all": n}[share]
+    b = tuple(np.concatenate([x[:keep], y[keep:]]) for x, y in zip(a, fresh))
+    return [build_edge_table(*map(jnp.asarray, t)) for t in (a, b)]
+
+
+def _own_cache(jitted, **kw):
+    """`jitted` again, with a program cache of its own: jit shares one
+    cache among the wrappers of one function."""
+    inner = jitted.__wrapped__
+    return jax.jit(functools.wraps(inner)(lambda *a, **k: inner(*a, **k)),
+                   **kw)
+
+
+@pytest.mark.parametrize("share", ["none", "partial", "all"])
+@pytest.mark.parametrize("cap", [64, 512, 8192])
+def test_dictionary_path_is_bit_exact_with_one_program_per_capacity(
+        rng, monkeypatch, cap, share):
+    """The dictionary path leaves the raw path's store and snapshot at
+    every share of hits, and its programs depend on the table's capacity
+    alone: one rewrite, one commit and one admission program serve a
+    cold table and a table with any share of hits."""
+    from repro.compress import stage as stage_mod
+    from repro.graphstore import store as store_mod
+    from repro.query.snapshot import build_snapshot
+
+    fresh = {
+        "rewrite_table": _own_cache(stage_mod.rewrite_table,
+                                    static_argnames=("star_min", "hot_min")),
+        "admit_committed": _own_cache(stage_mod.admit_committed,
+                                      static_argnames=("ttl",)),
+    }
+    for name, fn in fresh.items():
+        monkeypatch.setattr(stage_mod, name, fn)
+    commit = _own_cache(store_mod.commit_compressed)
+
+    tables = _tables(rng, cap, share)
+    store_a = store_b = init_store(1 << 17, 1 << 16)
+    stage = DictionaryStage(capacity=4 * cap, star_min=1, hot_min=1)
+    refs = []
+    for et in tables:
+        store_a, _ = ingest_step(store_a, et)
+        cc = stage.rewrite(et)
+        store_b, s = commit(store_b, cc)
+        stage.observe_commit(cc, s)
+        refs.append(int(s["dict_refs"]))
+        assert int(s["dropped_inserts"]) == 0
+    n_b = int(tables[1].n_edges)
+    assert refs[0] == 0
+    if share == "none":
+        assert refs[1] == 0
+    elif share == "partial":
+        assert 0 < refs[1] < n_b
+    else:
+        assert refs[1] == n_b
+    for f in dataclasses.fields(store_a):
+        a, b = getattr(store_a, f.name), getattr(store_b, f.name)
+        assert jnp.array_equal(a, b), f"store field {f.name} diverged"
+    snap_a, snap_b = build_snapshot(store_a), build_snapshot(store_b)
+    for f in dataclasses.fields(snap_a):
+        a, b = getattr(snap_a, f.name), getattr(snap_b, f.name)
+        assert jnp.array_equal(a, b), f"snapshot field {f.name} diverged"
+    assert commit._cache_size() == 1
+    for name, fn in fresh.items():
+        assert fn._cache_size() == 1, name
+
+
+def test_rewrite_pulls_nothing_to_the_host(rng, monkeypatch):
+    """`rewrite` chooses no shape from data: it reads no device value on
+    the host, on a cold dictionary or with hits."""
+    from jax._src.array import ArrayImpl
+
+    tables = _tables(rng, 128, "partial")
+    stage = DictionaryStage(capacity=512, star_min=1, hot_min=1)
+    store = init_store(1 << 12, 1 << 12)
+    pulls = []
+    value = ArrayImpl._value
+
+    def spy(self):
+        pulls.append(self.shape)
+        return value.fget(self)
+
+    for et in tables:
+        monkeypatch.setattr(ArrayImpl, "_value", property(spy))
+        cc = stage.rewrite(et)
+        monkeypatch.setattr(ArrayImpl, "_value", value)
+        store, s = commit_compressed(store, cc)
+        stage.observe_commit(cc, s)
+    assert pulls == []
+    assert int(s["dict_refs"]) > 0  # the second rewrite had hits
 
 
 def test_commit_compressed_accounting(rng):
