@@ -157,8 +157,8 @@ def dict_admit(d: PatternDictionary, keys: jax.Array, admit: jax.Array,
     n_evicted = jnp.sum(evict.astype(jnp.int32))
     refcount = jnp.where(evict, 0, d.refcount)
 
-    sig, slot, is_new = upsert_sweep(sig, keys, admit,
-                                     jnp.asarray(DICT_PROBES, jnp.int32))
+    sig, slot, is_new, _ = upsert_sweep(sig, keys, admit,
+                                        jnp.asarray(DICT_PROBES, jnp.int32))
     placed = admit & (slot >= 0)
     new = is_new & admit
     tgt_new = jnp.where(new, slot, cap)

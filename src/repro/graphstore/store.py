@@ -142,10 +142,12 @@ def ingest_step(store: GraphStore, et) -> Tuple[GraphStore, dict]:
     maintenance.
 
     Probe-work counters, one pair per sweep: `node_rounds_run` /
-    `edge_rounds_run`, the rounds the sweep's loop executed (today its
-    budget), and `node_rounds_needed` / `edge_rounds_needed`, the rounds
-    that placed its last lane (`repro.kernels.upsert.rounds_needed`;
-    a dropped lane counts as the budget)."""
+    `edge_rounds_run`, the rounds the sweep's loop executed (it ends
+    once its last lane is placed, so this is capped by the budget, and
+    is the whole budget when a lane is dropped), and
+    `node_rounds_needed` / `edge_rounds_needed`, the rounds that placed
+    its last lane, read from the slots (`repro.kernels.upsert.
+    rounds_needed`; a dropped lane counts as the budget)."""
     from repro.core.compression import mix_keys
     from repro.kernels import ops
     from repro.kernels.upsert import rounds_needed
@@ -165,7 +167,7 @@ def ingest_step(store: GraphStore, et) -> Tuple[GraphStore, dict]:
 
     # ---- nodes: MERGE (one fused probe sweep) ----
     with jax.named_scope("node_upsert"):
-        nk, nslot, n_isnew = ops.fused_upsert(
+        nk, nslot, n_isnew, n_rounds = ops.fused_upsert(
             store.node_keys, et.node_ids, et.node_valid, n_probes_n)
     node_placed = et.node_valid & (nslot >= 0)
     is_new = n_isnew & et.node_valid
@@ -179,7 +181,7 @@ def ingest_step(store: GraphStore, et) -> Tuple[GraphStore, dict]:
     with jax.named_scope("store_scatter"):
         ekey = mix_keys(et.src, et.dst, et.etype)
     with jax.named_scope("edge_upsert"):
-        ek, eslot, e_isnew = ops.fused_upsert(
+        ek, eslot, e_isnew, e_rounds = ops.fused_upsert(
             store.edge_keys, ekey, et.edge_valid, n_probes_e)
     edge_placed = et.edge_valid & (eslot >= 0)
     e_new = e_isnew & et.edge_valid
@@ -234,10 +236,9 @@ def ingest_step(store: GraphStore, et) -> Tuple[GraphStore, dict]:
         # the budget the sweeps ran with, the controller's pressure input
         "probe_rounds": jnp.maximum(n_probes_n, n_probes_e),
         # probe work per sweep: the rounds each loop executed, and the
-        # rounds that placed its last lane (upsert.rounds_needed); a
-        # sweep that ends early must keep `*_rounds_run` its true count
-        "node_rounds_run": n_probes_n,
-        "edge_rounds_run": n_probes_e,
+        # rounds that placed its last lane (upsert.rounds_needed)
+        "node_rounds_run": n_rounds,
+        "edge_rounds_run": e_rounds,
         "node_rounds_needed": rounds_needed(
             et.node_ids, nslot, et.node_valid, ncap, n_probes_n),
         "edge_rounds_needed": rounds_needed(

@@ -31,7 +31,8 @@ IMPL = {
 def fused_upsert(table_keys, keys, valid, n_probes):
     """Fused lookup-or-insert (GRAPHPUSH commit hot path): one probe
     sweep per table instead of lookup-then-insert.  Returns
-    (table_keys', slot (-1 = dropped), is_new)."""
+    (table_keys', slot (-1 = dropped), is_new, rounds), `rounds` the
+    probe rounds the sweep ran (it ends once every lane is placed)."""
     return _upsert.fused_upsert_ref(table_keys, keys, valid, n_probes)
 
 

@@ -13,8 +13,13 @@ fused sweep is bit-identical to lookup-then-insert.
 
 The probe budget is *dynamic* (a traced scalar): the caller doubles it
 as the table load factor grows (adaptive probing, ROADMAP "store
-probing robustness"), so the loop is a `while` with a data-dependent
-trip count rather than a statically unrolled scan.
+probing robustness").  The loop is a `while` that also ends once every
+valid lane is resolved, so its trip count is the round that placed the
+last lane, capped by the budget; a lane that is never placed keeps it
+running to the whole budget.  A round after the last lane resolved
+would change nothing (every lane is masked off and scatters to the
+dropped index), so the early exit is bit-identical to running the
+budget out.  The sweep returns its trip count beside its results.
 
 `upsert_sweep` is the pure body shared verbatim by the Pallas kernel
 and the jnp oracle `fused_upsert_ref` (repro.kernels.ref style), so
@@ -46,10 +51,10 @@ def rounds_needed(keys: jax.Array, slot: jax.Array, valid: jax.Array,
     cap, 0)) mod cap) + 1``.  A valid lane that was dropped (``slot`` -1)
     counts as the whole budget ``n_probes``; 0 when no lane is valid.
 
-    Read from the slots a sweep returned, so the sweep itself is not
-    touched.  A sweep of this many rounds places every lane as the full
-    budget did (`upsert_sweep` resolves a lane at the first round that
-    hits its key or wins an empty slot)."""
+    Read from the slots a sweep returned, independently of the sweep's
+    loop: `upsert_sweep` resolves a lane at the first round that hits
+    its key or wins an empty slot and stops after the last such round,
+    so this equals the trip count it returns."""
     home = probe_hash(keys, cap, jnp.zeros(keys.shape, jnp.int32))
     placed = valid & (slot >= 0)
     rounds = jnp.where(placed, jnp.mod(slot - home, cap) + 1, 0)
@@ -62,15 +67,22 @@ def upsert_sweep(table_keys: jax.Array, keys: jax.Array, valid: jax.Array,
                  n_probes: jax.Array):
     """Single-pass fused upsert of UNIQUE keys (pre-deduplicated batch).
 
-    Returns (table_keys', slot (int32, -1 = dropped), is_new (bool)).
-    `n_probes` may be a traced scalar (adaptive probe budget).  Races
-    for empty slots resolve by scatter-max; losers keep probing.
+    Returns (table_keys', slot (int32, -1 = dropped), is_new (bool),
+    rounds (int32)).  `n_probes` may be a traced scalar (adaptive probe
+    budget).  Races for empty slots resolve by scatter-max; losers keep
+    probing.  `rounds` is the loop's trip count: the round that placed
+    the last valid lane, capped by `n_probes` (the whole budget when a
+    lane is dropped, 0 when no lane is valid).
     """
     cap = table_keys.shape[0]
     n = keys.shape[0]
 
-    def body(i, carry):
-        tk, slot, is_new, done = carry
+    def pending(carry):
+        i, _, _, _, done = carry
+        return (i < n_probes) & ~jnp.all(done)
+
+    def body(carry):
+        i, tk, slot, is_new, done = carry
         cand = probe_hash(keys, cap, jnp.full((n,), i, jnp.int32))
         cur = tk[cand]
         hit = (cur == keys) & valid & ~done
@@ -81,12 +93,13 @@ def upsert_sweep(table_keys: jax.Array, keys: jax.Array, valid: jax.Array,
         slot = jnp.where(placed, cand, slot)
         is_new = is_new | won
         done = done | placed
-        return tk, slot, is_new, done
+        return i + 1, tk, slot, is_new, done
 
-    tk, slot, is_new, _ = jax.lax.fori_loop(
-        0, n_probes, body,
-        (table_keys, jnp.full((n,), -1, jnp.int32), jnp.zeros((n,), bool), ~valid))
-    return tk, slot, is_new
+    rounds, tk, slot, is_new, _ = jax.lax.while_loop(
+        pending, body,
+        (jnp.int32(0), table_keys, jnp.full((n,), -1, jnp.int32),
+         jnp.zeros((n,), bool), ~valid))
+    return tk, slot, is_new, rounds
 
 
 @jax.jit
@@ -99,12 +112,13 @@ def fused_upsert_ref(table_keys: jax.Array, keys: jax.Array, valid: jax.Array,
 
 
 def _upsert_kernel(probes_ref, table_ref, keys_ref, valid_ref,
-                   table_out, slot_out, new_out):
-    tk, slot, is_new = upsert_sweep(
+                   table_out, slot_out, new_out, rounds_out):
+    tk, slot, is_new, rounds = upsert_sweep(
         table_ref[...], keys_ref[...], valid_ref[...] != 0, probes_ref[0])
     table_out[...] = tk
     slot_out[...] = slot
     new_out[...] = is_new.astype(jnp.int32)
+    rounds_out[0] = rounds
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -114,13 +128,14 @@ def fused_upsert(table_keys: jax.Array, keys: jax.Array, valid: jax.Array,
 
     table_keys (cap,) key dtype (0 = empty); keys (n,) unique batch;
     valid (n,) bool; n_probes scalar int32 (dynamic probe budget).
-    Returns (table_keys', slot (int32, -1 = dropped), is_new (bool)).
+    Returns (table_keys', slot (int32, -1 = dropped), is_new (bool),
+    rounds (int32, the sweep's trip count)).
     VMEM budget: table + batch keys resident (4 MB at cap = 1M uint32).
     """
     cap = table_keys.shape[0]
     n = keys.shape[0]
     probes = jnp.asarray(n_probes, jnp.int32).reshape(1)
-    tk, slot, new_i = pl.pallas_call(
+    tk, slot, new_i, rounds = pl.pallas_call(
         _upsert_kernel,
         grid=(1,),
         in_specs=[
@@ -133,12 +148,14 @@ def fused_upsert(table_keys: jax.Array, keys: jax.Array, valid: jax.Array,
             pl.BlockSpec((cap,), lambda i: (0,)),
             pl.BlockSpec((n,), lambda i: (0,)),
             pl.BlockSpec((n,), lambda i: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((cap,), table_keys.dtype),
             jax.ShapeDtypeStruct((n,), jnp.int32),
             jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((1,), jnp.int32),
         ],
         interpret=interpret,
     )(probes, table_keys, keys, valid.astype(jnp.int32))
-    return tk, slot, new_i != 0
+    return tk, slot, new_i != 0, rounds[0]

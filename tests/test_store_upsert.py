@@ -2,14 +2,17 @@
 
 Covers the PR-3 hot-path rewrite: Pallas-vs-jnp-oracle parity of the
 fused upsert, the 6 -> 2 probe-loop contract of `ingest_step`, the
-adaptive probe budget under table pressure (hypothesis property: a key
-is only dropped when its escalated probe window is genuinely
-exhausted), the table-pressure -> controller back-pressure, and
+sweep's early exit (bit-identical to running its whole budget, in the
+store, the dictionary and the sharded commit), the adaptive probe
+budget under table pressure (hypothesis property: a key is only
+dropped when its escalated probe window is genuinely exhausted), the
+table-pressure -> controller back-pressure, and
 bit-exact equivalence of `apply_delta` / `SnapshotMaintainer` against
 full `build_snapshot` recompaction after N random commits.
 """
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -75,11 +78,12 @@ def test_fused_upsert_kernel_matches_oracle(cap, n, probes, rng):
     valid = jnp.asarray(rng.random(n) < 0.9)
     # pre-populate some slots so hits, claims and races all occur
     table = jnp.zeros((cap,), jnp.uint32)
-    table, _, _ = fused_upsert_ref(table, keys[: n // 2], valid[: n // 2],
-                                   jnp.int32(probes))
+    table = fused_upsert_ref(table, keys[: n // 2], valid[: n // 2],
+                             jnp.int32(probes))[0]
     got = fused_upsert(table, keys, valid, jnp.int32(probes), interpret=True)
     want = fused_upsert_ref(table, keys, valid, jnp.int32(probes))
-    for g, w, name in zip(got, want, ("table", "slot", "is_new")):
+    assert len(got) == len(want) == 4
+    for g, w, name in zip(got, want, ("table", "slot", "is_new", "rounds")):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
                                       err_msg=name)
 
@@ -91,7 +95,7 @@ def test_fused_upsert_idempotent_and_consistent(rng):
                    replace=False))
     valid = jnp.ones((n,), bool)
     table0 = jnp.zeros((cap,), jnp.uint32)
-    table1, slot1, new1 = ops.fused_upsert(table0, keys, valid, MAX_PROBES)
+    table1, slot1, new1, _ = ops.fused_upsert(table0, keys, valid, MAX_PROBES)
     s1 = np.asarray(slot1)
     placed = s1 >= 0
     assert np.asarray(new1)[placed].all()  # empty table: every placed is new
@@ -99,7 +103,7 @@ def test_fused_upsert_idempotent_and_consistent(rng):
     assert len(set(s1[placed])) == placed.sum()
     assert (np.asarray(table1)[s1[placed]] == np.asarray(keys)[placed]).all()
     # re-upsert: pure lookup — same slots, nothing new, table unchanged
-    table2, slot2, new2 = ops.fused_upsert(table1, keys, valid, MAX_PROBES)
+    table2, slot2, new2, _ = ops.fused_upsert(table1, keys, valid, MAX_PROBES)
     np.testing.assert_array_equal(np.asarray(table2), np.asarray(table1))
     np.testing.assert_array_equal(np.asarray(slot2)[placed], s1[placed])
     assert not np.asarray(new2).any()
@@ -135,7 +139,7 @@ def test_high_load_drops_only_when_probing_exhausted():
             valid = jnp.arange(chunk) < len(part)
             bud = (probe_budget(jnp.int32(placed), cap) if adaptive
                    else jnp.int32(MAX_PROBES))
-            table, slot, _ = ops.fused_upsert(
+            table, slot, _, _ = ops.fused_upsert(
                 table, jnp.asarray(batch), valid, bud)
             slot = np.asarray(slot)[: len(part)]
             placed_mask[lo: lo + len(part)] = slot >= 0
@@ -161,7 +165,7 @@ def test_high_load_drops_only_when_probing_exhausted():
             assert (window != 0).all() and (window != key).all(), \
                 f"key {key} dropped with a free/own slot in its window"
         # placed keys are retrievable (upsert of them is a pure lookup)
-        _, slot2, new2 = ops.fused_upsert(
+        _, slot2, new2, _ = ops.fused_upsert(
             jnp.asarray(table), jnp.asarray(keys), jnp.asarray(placed_mask),
             probe_budget(jnp.int32(placed), cap))
         s2 = np.asarray(slot2)
@@ -225,10 +229,11 @@ def test_rounds_needed_matches_a_linear_probing_oracle(chains, want):
     kv = np.zeros(n, np.uint32)
     kv[: len(keys)] = keys
     valid = jnp.arange(n) < len(keys)
-    _, slot, _ = ops.fused_upsert(jnp.asarray(table, jnp.uint32),
-                                  jnp.asarray(kv), valid, jnp.int32(budget))
+    _, slot, _, ran = ops.fused_upsert(jnp.asarray(table, jnp.uint32),
+                                       jnp.asarray(kv), valid,
+                                       jnp.int32(budget))
     got = rounds_needed(jnp.asarray(kv), slot, valid, cap, jnp.int32(budget))
-    assert int(got) == want == max(r or budget for r in rounds)
+    assert int(got) == int(ran) == want == max(r or budget for r in rounds)
     # no valid lane: nothing needed
     none = rounds_needed(jnp.asarray(kv), slot, jnp.zeros(n, bool), cap,
                          jnp.int32(budget))
@@ -236,7 +241,9 @@ def test_rounds_needed_matches_a_linear_probing_oracle(chains, want):
 
 
 def test_ingest_step_counts_probe_rounds(rng):
-    """The loops run their budget; the rounds needed are the longest
+    """The loops stop once their last lane is placed, so each runs the
+    rounds it needs, within the budget, and the budget (`probe_rounds`)
+    is reported unchanged; the rounds needed are the longest
     linear-probing walk from a key's home to the slot the commit gave
     it, past slots that hold other keys; the ingestor sums both sweeps
     into its `CommitRecord`."""
@@ -251,8 +258,10 @@ def test_ingest_step_counts_probe_rounds(rng):
                                  src=et.src.astype(jnp.uint32),
                                  dst=et.dst.astype(jnp.uint32))
         s = ing.push(et)["stats"]
-        assert int(s["node_rounds_run"]) == int(s["edge_rounds_run"]) \
-            == int(s["probe_rounds"]) == MAX_PROBES
+        assert int(s["probe_rounds"]) == MAX_PROBES
+        for sweep in ("node", "edge"):
+            assert int(s[f"{sweep}_rounds_run"]) \
+                == int(s[f"{sweep}_rounds_needed"]) <= MAX_PROBES
         table = np.asarray(ing.store.node_keys).tolist()
         walks = []
         for k, slot in zip(np.asarray(et.node_ids).tolist(),
@@ -267,11 +276,179 @@ def test_ingest_step_counts_probe_rounds(rng):
         assert int(s["node_rounds_needed"]) == max(walks)
         longest = max(longest, max(walks))
         rec = ing.commits[-1]
-        assert rec.rounds_run == 2 * MAX_PROBES
+        assert rec.rounds_run == (int(s["node_rounds_run"])
+                                  + int(s["edge_rounds_run"]))
         assert rec.rounds_needed == (int(s["node_rounds_needed"])
                                      + int(s["edge_rounds_needed"]))
         assert 1 <= int(s["edge_rounds_needed"]) <= MAX_PROBES
     assert longest > 1  # some chain was walked
+
+
+@jax.jit
+def _full_budget_sweep(table_keys, keys, valid, n_probes):
+    """The sweep without its early exit: every round of the budget runs,
+    each lane resolved at the first round that hits its key or wins an
+    empty slot.  The reference the early-exit sweep is held to."""
+    cap, n = table_keys.shape[0], keys.shape[0]
+
+    def body(i, carry):
+        tk, slot, is_new, done = carry
+        cand = probe_hash(keys, cap, jnp.full((n,), i, jnp.int32))
+        cur = tk[cand]
+        hit = (cur == keys) & valid & ~done
+        empty = (cur == 0) & valid & ~done
+        tk = tk.at[jnp.where(empty, cand, cap)].max(keys, mode="drop")
+        won = empty & (tk[cand] == keys)
+        placed = hit | won
+        return (tk, jnp.where(placed, cand, slot), is_new | won,
+                done | placed)
+
+    tk, slot, is_new, _ = jax.lax.fori_loop(
+        0, n_probes, body,
+        (table_keys, jnp.full((n,), -1, jnp.int32), jnp.zeros((n,), bool),
+         ~valid))
+    return tk, slot, is_new
+
+
+@pytest.mark.parametrize("load,n_valid,budget", [
+    (0.2, 96, MAX_PROBES),          # low load: the sweep ends early
+    (0.65, 96, 2 * MAX_PROBES),     # past 0.6: the budget doubles
+    (0.95, 96, 4 * MAX_PROBES),     # saturated: lanes drop, budget runs out
+    (0.2, 0, MAX_PROBES),           # no valid lane: no round runs
+], ids=["low_load", "budget_doubled", "saturated_drops", "all_invalid"])
+def test_early_exit_sweep_matches_full_budget_loop(load, n_valid, budget,
+                                                   rng):
+    """The sweep that stops once its last lane is placed gives the same
+    table, slots and is_new bits as running its whole budget, and its
+    trip count is the rounds that placed its last lane."""
+    cap, n = 512, 128
+    pool = rng.choice(np.arange(1, 1 << 30, dtype=np.uint32),
+                      size=cap + n, replace=False)
+    # fill to `load` with a budget of the whole table, so nothing drops
+    old = pool[: int(cap * load)]
+    table = _full_budget_sweep(jnp.zeros((cap,), jnp.uint32),
+                               jnp.asarray(old), jnp.ones(len(old), bool),
+                               jnp.int32(cap))[0]
+    n_used = int((np.asarray(table) != 0).sum())
+    assert n_used == len(old)
+    assert int(probe_budget(jnp.int32(n_used), cap)) == budget
+    # half the batch hits keys already stored, half is new; tail invalid
+    keys = np.concatenate([rng.choice(old, n // 2, replace=False),
+                           pool[cap: cap + n // 2]])
+    valid = jnp.arange(n) < n_valid
+    args = (table, jnp.asarray(keys), valid, jnp.int32(budget))
+    tk, slot, is_new, rounds = ops.fused_upsert(*args)
+    for g, w, name in zip((tk, slot, is_new), _full_budget_sweep(*args),
+                          ("table", "slot", "is_new")):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=name)
+    assert int(rounds) == int(rounds_needed(args[1], slot, valid, cap,
+                                            args[3]))
+    dropped = int(np.sum(np.asarray(valid) & (np.asarray(slot) < 0)))
+    if n_valid == 0:
+        assert int(rounds) == 0
+        assert (np.asarray(slot) == -1).all()
+    elif load > 0.8:
+        assert dropped > 0 and int(rounds) == budget
+    else:
+        assert dropped == 0 and 0 < int(rounds) < budget
+
+
+def test_dict_admit_matches_full_budget_sweep(monkeypatch, rng):
+    """The dictionary's admit sweep, which now stops early, leaves the
+    same `PatternDictionary` as with the sweep that runs its budget out,
+    across hits, new entries and aging eviction past the high-water
+    mark."""
+    from repro.compress import dictionary as dct
+
+    cap, n = 256, 64
+    d = dct.init_dictionary(cap, jnp.uint32)
+    full = lambda *a: (*_full_budget_sweep(*a), None)  # noqa: E731
+    pool = rng.choice(np.arange(1, 1 << 30, dtype=np.uint32), size=8 * n,
+                      replace=False)
+    prev = pool[:0]
+    for step in range(8):
+        fresh = pool[step * n: step * n + n - len(prev) // 2]
+        keys = np.concatenate([prev[: len(prev) // 2], fresh])
+        admit = jnp.asarray(rng.random(n) < 0.9)
+        slots = [jnp.asarray(rng.integers(0, 1 << 12, n), jnp.int32)
+                 for _ in range(3)]
+        args = (d, jnp.asarray(keys), admit, *slots, jnp.asarray(keys))
+        got = dct.dict_admit(*args, ttl=2)
+        with monkeypatch.context() as m:
+            m.setattr(dct, "upsert_sweep", full)
+            want = dct.dict_admit.__wrapped__(*args, ttl=2, high_water=0.85)
+        for f in dataclasses.fields(want):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(got, f.name)),
+                np.asarray(getattr(want, f.name)),
+                err_msg=f"step {step}: {f.name}")
+        d = dataclasses.replace(got, tick=got.tick + 3)  # age the entries
+        prev = keys
+    assert int(d.evictions) > 0  # past the high-water mark: eviction ran
+
+
+_SHARDED = """
+import json
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import Mesh
+from repro.graphstore.store import init_store, make_distributed_ingest
+from repro.kernels.upsert import probe_hash
+
+D, n = 4, 2048
+mesh = Mesh(np.array(jax.devices()[:D]), ("data",))
+rng = np.random.default_rng(11)
+src = jnp.asarray(rng.integers(1, 1 << 24, n), jnp.uint32)
+dst = jnp.asarray(rng.integers(1, 1 << 24, n), jnp.uint32)
+etype = jnp.asarray(rng.integers(0, 3, n), jnp.int32)
+valid = jnp.ones(n, bool)
+store = init_store(1 << 14, 1 << 13, key_dtype=jnp.uint32)
+store, s = jax.jit(make_distributed_ingest(mesh))(store, src, dst, etype,
+                                                  valid)
+
+def walks(table):
+    # one commit into an empty store: every stored key was placed by it,
+    # at the round given by its distance from its home slot
+    out = []
+    for part in np.split(np.asarray(table), D):
+        cap, at = len(part), np.flatnonzero(part)
+        home = np.asarray(probe_hash(jnp.asarray(part[at]), cap,
+                                     jnp.zeros(len(at), jnp.int32)))
+        out.append(int(((at - home) % cap).max()) + 1)
+    return out
+
+print(json.dumps({"node_walks": walks(store.node_keys),
+                  "edge_walks": walks(store.edge_keys),
+                  **{k: int(s[k]) for k in (
+                      "dropped_inserts", "node_rounds_run", "edge_rounds_run",
+                      "node_rounds_needed", "edge_rounds_needed")}}))
+"""
+
+
+def test_distributed_ingest_reports_max_rounds_across_shards():
+    """Each shard's sweeps stop on its own lanes, so shards run different
+    numbers of rounds; the sharded commit reports the largest."""
+    import json
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join(
+                   [src, os.environ.get("PYTHONPATH", "")]))
+    out = subprocess.run([sys.executable, "-c", _SHARDED], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    r = json.loads(out.stdout.strip().splitlines()[-1])
+    assert r["dropped_inserts"] == 0
+    for sweep in ("node", "edge"):
+        per_shard = r[f"{sweep}_walks"]
+        assert len(set(per_shard)) > 1  # the shards' trip counts differ
+        assert r[f"{sweep}_rounds_run"] == r[f"{sweep}_rounds_needed"] \
+            == max(per_shard) < MAX_PROBES
 
 
 # ---------------------------------------------------------------------------
