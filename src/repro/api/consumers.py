@@ -1,8 +1,7 @@
 """Consumer implementations (the store-engine load model).
 
-`SimulatedConsumer` is the queued-consumer model extracted from the
-original `IngestionPipeline._consume_mu`: a finite-capacity engine
-with a commit queue.  Sustained over-delivery pins mu at 1.0 (the
+`SimulatedConsumer` is the queued-consumer model: a finite-capacity
+engine with a commit queue.  Sustained over-delivery pins mu at 1.0 (the
 Fig. 2 meltdown) and builds backlog — exactly the system-delay term
 alpha of Eq. 3.
 

@@ -79,7 +79,7 @@ class MetricsHub:
         # find the judge next to the signals
         self.monitor = None
         # the attached repro.lineage.LineageTracker, when one is wired
-        # (PipelineBuilder.with_lineage): `controlled_tick` looks it up
+        # (PipelineBuilder.with_lineage): `push_batch` looks it up
         # here to tag batches; None keeps the hot path branch-only
         self.lineage = None
 

@@ -66,7 +66,8 @@ class Consumer(Protocol):
 @runtime_checkable
 class Sink(Protocol):
     """Commit target (Algorithm 3 GRAPHPUSH or any store binding).
-    Returns the commit stats dict: at minimum `committed`, plus `rho`
-    (bucket diversity) when the commit landed."""
+    Returns the commit outcome: `committed`, and when the commit landed
+    the keys `GraphIngestor.push` returns (`rho`, `dropped`,
+    `probe_rounds`, `pressure`, `refs`, `dict_hit_rate`; docs/API.md)."""
 
     def commit(self, et, now: Optional[float] = None) -> Dict: ...

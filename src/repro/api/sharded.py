@@ -11,17 +11,15 @@ the control law needs no modification to go multi-collector.
 from __future__ import annotations
 
 import dataclasses
-import time
 import zlib
-from typing import Callable, Iterable, List, Optional, Sequence
+from typing import Callable, List, Optional, Sequence
 
 import numpy as np
 
-from repro.api.consumers import SimulatedConsumer
 from repro.api.metrics import MetricsHub, PipelineEvent, PipelineReport
-from repro.api.pipeline import controlled_tick
-from repro.api.protocols import Source, TickContext
-from repro.api.sinks import GraphStoreSink
+from repro.api.pipeline import (TickLoop, controlled_tick, intake,
+                                new_loop_state)
+from repro.api.protocols import Source
 from repro.api.stages import BufferControlStage, FilterStage, TransformStage
 from repro.configs.paper_ingest import IngestConfig
 
@@ -52,7 +50,7 @@ class ShardedReport:
         return [r.samples["mu"] for r in self.shards]
 
 
-class ShardedPipeline:
+class ShardedPipeline(TickLoop):
     def __init__(
         self,
         cfg: Optional[IngestConfig] = None,
@@ -69,19 +67,10 @@ class ShardedPipeline:
     ):
         if n_shards < 1:
             raise ValueError("n_shards must be >= 1")
-        self.cfg = cfg or IngestConfig()
+        super().__init__(cfg, source, filter_stage, transform, consumer,
+                         sink, metrics, stages)
         self.n_shards = n_shards
-        self.source = source
-        self.filter_stage = filter_stage or FilterStage()
-        self.stages = list(stages)  # extra Stage-protocol record stages
-        self.transform = transform or TransformStage(
-            max_edges_per_batch=self.cfg.max_edges_per_batch)
-        self.consumer = consumer or SimulatedConsumer()
-        self.sink = sink or GraphStoreSink(
-            node_cap=self.cfg.store_nodes, edge_cap=self.cfg.store_edges)
         self.shard_key = shard_key or default_shard_key
-        self.metrics = metrics or MetricsHub()
-        self.telemetry = self.metrics.telemetry
         self.shards = [
             BufferControlStage(cfg=self.cfg, spill_dir=f"{spill_dir}/shard{i}")
             for i in range(n_shards)
@@ -96,17 +85,13 @@ class ShardedPipeline:
             hub.subscribe(lambda ev, si=si: self._forward(ev, si))
         # per-shard cross-tick loop scalars; pipeline-owned so
         # checkpoint/resume (repro.resilience) can capture/restore them
-        self.loop_states: Optional[List[dict]] = None
+        self.loop_states = [new_loop_state(self.cfg) for _ in range(n_shards)]
 
     def _forward(self, ev: PipelineEvent, shard: int):
         # route through emit (not the hooks directly) so the aggregate
         # hub's counters see shard-level spill/drain/commit events too;
         # subscribers keep receiving the shard-tagged payload
         self.metrics.emit(ev.kind, ev.t, **{**ev.payload, "shard": shard})
-
-    @property
-    def store(self):
-        return self.sink.store
 
     def _partition(self, records: List[dict]) -> List[List[dict]]:
         parts: List[List[dict]] = [[] for _ in range(self.n_shards)]
@@ -115,116 +100,57 @@ class ShardedPipeline:
             parts[h % self.n_shards].append(r)
         return parts
 
-    # ------------------------------------------------------------------
-    def _shard_step(self, si: int, part: List[dict], now: float, dt: float,
-                    state: dict):
-        """One controlled tick on shard `si`: the exact single-shard
-        loop body (`controlled_tick`), with this shard's slice of the
-        shared consumer's capacity (dt/N, so N shards together drain
-        one consumer-tick, not N)."""
-        buf = self.shards[si]
-        buf.perfmon.observe_rate(now, len(part))
-        state["records"] += len(part)
-        buf.extend(part)
-        with self.telemetry.span("shard.tick", shard=si):
-            controlled_tick(buf, self.transform, self.sink, self.consumer,
-                            self._hubs[si], state, now, dt,
-                            consume_dt=dt / self.n_shards)
+    def _step(self, recs, now: float, dt: float):
+        """Partition the tick, then run the exact single-shard loop body
+        (`controlled_tick`) on each shard, with its slice of the shared
+        consumer's capacity (dt/N, so N shards together drain one
+        consumer-tick, not N)."""
+        with self.telemetry.span("partition"):
+            parts = self._partition(recs)
+        for si, part in enumerate(parts):
+            buf, state = self.shards[si], self.loop_states[si]
+            intake(buf, state, part, now)
+            with self.telemetry.span("shard.tick", shard=si):
+                controlled_tick(buf, self.transform, self.sink, self.consumer,
+                                self._hubs[si], state, now, dt,
+                                consume_dt=dt / self.n_shards)
 
-    # ------------------------------------------------------------------
-    def run(self, source_ticks: Optional[Iterable] = None,
-            max_ticks: int = 300) -> ShardedReport:
-        if source_ticks is None:
-            if self.source is None:
-                raise ValueError("no source: pass source_ticks or set source")
-            source_ticks = self.source.ticks()
-        t_start = time.time()
+    def _report(self, wall_s: float) -> ShardedReport:
         states = self.loop_states
-        if states is None:
-            states = [
-                {"last_beta_e": self.cfg.beta_init, "last_mu": 0.0,
-                 "records": 0, "instr": 0, "raw": 0, "crs": []}
-                for _ in range(self.n_shards)
-            ]
-            self.loop_states = states
-        tel = self.telemetry
-        for i, tick in enumerate(source_ticks):
-            if i >= max_ticks:
-                break
-            now, dt = tick.t, 1.0
-            ctx = TickContext(t=now, dt=dt, index=i)
-            with tel.span("tick"):
-                with tel.span("filter"):
-                    recs = self.filter_stage(tick.records, ctx)
-                for stage in self.stages:
-                    recs = stage(recs, ctx)
-                self.metrics.emit("tick", now, raw=len(tick.records),
-                                  kept=len(recs))
-                with tel.span("partition"):
-                    parts = self._partition(recs)
-                for si, part in enumerate(parts):
-                    self._shard_step(si, part, now, dt, states[si])
-
-        wall = time.time() - t_start
-        # the partition is total: per-shard record counts sum to the
-        # filtered stream (and survive checkpoint/resume, unlike a local)
-        total_records = sum(st["records"] for st in states)
         reports = [
             hub.build_report(
                 total_records=st["records"],
                 total_instructions=st["instr"],
                 raw_instructions=st["raw"],
                 compression_ratios=st["crs"],
-                wall_s=wall,
+                wall_s=wall_s,
             )
             for hub, st in zip(self._hubs, states)
         ]
         return ShardedReport(
             shards=reports,
-            total_records=total_records,
+            # the partition is total: per-shard record counts sum to the
+            # filtered stream (and survive checkpoint/resume)
+            total_records=sum(st["records"] for st in states),
             total_instructions=sum(st["instr"] for st in states),
             raw_instructions=sum(st["raw"] for st in states),
             max_buffered=[b.max_buffered for b in self.shards],
             spill_events=sum(h.counters["spill"] for h in self._hubs),
             drain_events=sum(h.counters["drain"] for h in self._hubs),
-            wall_s=wall,
+            wall_s=wall_s,
         )
 
     # ---- checkpoint surface (repro.resilience) -----------------------
     def state(self) -> dict:
-        s: dict = {
-            "loops": None if self.loop_states is None else
-                [{**st, "crs": list(st["crs"])} for st in self.loop_states],
-            "shards": [b.state() for b in self.shards],
-            "hubs": [h.state() for h in self._hubs],
-            "metrics": self.metrics.state(),
-            "stages": [st.state() if hasattr(st, "state") else None
-                       for st in self.stages],
-        }
-        if hasattr(self.consumer, "state"):
-            s["consumer"] = self.consumer.state()
-        if hasattr(self.sink, "state"):
-            s["sink"] = self.sink.state()
-        tracker = getattr(self.metrics, "lineage", None)
-        if tracker is not None:
-            s["lineage"] = tracker.state()
-        return s
+        return {"loops": [{**st, "crs": list(st["crs"])}
+                          for st in self.loop_states],
+                "shards": [b.state() for b in self.shards],
+                "hubs": [h.state() for h in self._hubs], **super().state()}
 
     def restore_state(self, s: dict) -> None:
-        self.loop_states = None if s["loops"] is None else \
-            [dict(st) for st in s["loops"]]
+        self.loop_states = [dict(st) for st in s["loops"]]
         for b, b_s in zip(self.shards, s["shards"]):
             b.restore_state(b_s)
         for h, h_s in zip(self._hubs, s["hubs"]):
             h.restore_state(h_s)
-        self.metrics.restore_state(s["metrics"])
-        for st, st_s in zip(self.stages, s["stages"]):
-            if st_s is not None and hasattr(st, "restore_state"):
-                st.restore_state(st_s)
-        if "consumer" in s and hasattr(self.consumer, "restore_state"):
-            self.consumer.restore_state(s["consumer"])
-        if "sink" in s and hasattr(self.sink, "restore_state"):
-            self.sink.restore_state(s["sink"])
-        tracker = getattr(self.metrics, "lineage", None)
-        if tracker is not None and "lineage" in s:
-            tracker.restore_state(s["lineage"])
+        super().restore_state(s)

@@ -29,7 +29,7 @@ the table's lanes, and the counter `rewrite.lanes_idle` counts the
 lanes of the residual and of the references that hold no edge.
 
 `CompressedCommit` duck-types the `EdgeTable` surface the rest of the
-system reads (`controlled_tick` metadata, `sketch_update` fields) at
+system reads (`push_batch` metadata, `sketch_update` fields) at
 the table's own shapes, so sinks, sketches and the snapshot maintainer
 observe compressed commits unchanged.
 """
@@ -123,7 +123,7 @@ class CompressedCommit:
     def node_valid(self):
         return self.residual.node_valid
 
-    # ---- table-level metadata (controlled_tick reads these) ----
+    # ---- table-level metadata (push_batch reads these) ----
     def density(self) -> jax.Array:
         v = jnp.maximum(self.residual.n_nodes.astype(jnp.float32), 2.0)
         return 2.0 * self.n_edges_full.astype(jnp.float32) / (v * (v - 1.0))

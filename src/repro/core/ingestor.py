@@ -246,27 +246,26 @@ class GraphIngestor:
             with tel.span("commit.fetch"):
                 nrun, erun, nneed, eneed = jax.device_get(
                     [s[k] for k in _ROUND_KEYS])
+                instructions = int(s["instructions"])
+                new_nodes = int(s["new_nodes"])
                 rec = CommitRecord(
                     t=wall,
                     busy_s=busy,
-                    instructions=int(s["instructions"]),
-                    new_nodes=int(s["new_nodes"]),
+                    instructions=instructions,
+                    new_nodes=new_nodes,
                     batch_nodes=int(s["batch_nodes"]),
                     ok=True,
-                    probe_rounds=int(s.get("probe_rounds", 0)),
-                    dropped=int(s.get("dropped_inserts", 0)),
-                    refs=int(s.get("dict_refs", 0)),
+                    probe_rounds=int(s["probe_rounds"]),
+                    dropped=int(s["dropped_inserts"]),
+                    refs=int(s["dict_refs"]) if compressed else 0,
                     rounds_run=int(nrun) + int(erun),
                     rounds_needed=int(nneed) + int(eneed),
-                )
-                if compressed:
                     # every edge of the table holds one of these lanes
-                    rec.lanes_idle = et.lanes - (rec.instructions
-                                                 - rec.new_nodes)
-                pressure = max(float(s.get("node_load", 0.0)),
-                               float(s.get("edge_load", 0.0)))
-                hit_rate = (float(s["dict_hit_rate"])
-                            if "dict_refs" in s else None)
+                    lanes_idle=(et.lanes - (instructions - new_nodes)
+                                if compressed else 0),
+                )
+                pressure = max(float(s["node_load"]), float(s["edge_load"]))
+                hit_rate = float(s["dict_hit_rate"]) if compressed else None
             self.commits.append(rec)
             if self.lineage is not None and tag is not None:
                 # store took it: the committed low watermark may advance
@@ -281,23 +280,22 @@ class GraphIngestor:
                 # update) has run: queries can now SEE these records —
                 # only here does the queryable watermark advance
                 self.lineage.mark_queryable(tag, wall)
-            rho = rec.new_nodes / max(rec.batch_nodes, 1)
-            out = {
+            # the commit outcome: the same keys on every successful commit
+            return {
                 "committed": True,
                 "stats": s,
                 "busy_s": busy,
-                "rho": rho,
+                "rho": rec.new_nodes / max(rec.batch_nodes, 1),
                 "instructions": rec.instructions,
                 # table-pressure signals for the Algorithm-2 controller
                 "dropped": rec.dropped,
                 "probe_rounds": rec.probe_rounds,
                 "pressure": pressure,
+                # compressibility signals (repro.compress -> controller);
+                # 0 and None for a table the dictionary did not rewrite
+                "refs": rec.refs,
+                "dict_hit_rate": hit_rate,
             }
-            if "dict_refs" in s:
-                # compressibility signals (repro.compress -> controller)
-                out["refs"] = rec.refs
-                out["dict_hit_rate"] = hit_rate
-            return out
         except ConnectionError:
             # commit failed (network/DBMS) -> archive for replay.
             # `wall`, not `now or time.time()`: now=0.0 is falsy, so the

@@ -2,7 +2,7 @@
 
 `IMPL` names what each op runs.  A Pallas kernel is declared for an op
 only where it compiles for TPU v5e at the main path's widths and is
-bit-exact with its jnp oracle.  None does yet (ROADMAP S7 records each
+bit-exact with its jnp oracle.  None does yet (ROADMAP D3 records each
 kernel's compiler refusal), so every op runs its XLA implementation,
 the same one on TPU and on CPU.
 

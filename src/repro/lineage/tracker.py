@@ -167,7 +167,7 @@ class LineageTracker:
     """Watermarks + per-path freshness + per-batch hop logs for a run.
 
     Wiring (done by `PipelineBuilder.with_lineage`): the buffer
-    stage(s) call `observe_intake` on every `extend`; `controlled_tick`
+    stage(s) call `observe_intake` on every `extend`; `push_batch`
     opens a tag per batch and hands it to the ingestor; the ingestor
     marks pool/archive/commit/queryable transitions as the batch moves
     through GRAPHPUSH; `bind(hub)` subscribes the tracker so every

@@ -1,5 +1,9 @@
-"""Composable-API tests: protocol round-trips, builder facade,
-wrapper parity, metrics hooks, and the sharded scale-out scenario."""
+"""Composable-API tests: protocol round-trips, builder facade, the
+push body's golden run, the commit outcome, the spans of one tick,
+metrics hooks, and the sharded scale-out scenario."""
+import json
+import os
+
 import numpy as np
 import pytest
 
@@ -20,7 +24,6 @@ from repro.api import (
 from repro.api.consumers import MeasuredConsumer
 from repro.configs.paper_ingest import IngestConfig
 from repro.core.ingestor import GraphIngestor
-from repro.core.pipeline import IngestionPipeline
 from repro.graphstore.store import init_store
 from repro.ingest.sources import BurstyTweetSource, FileReplaySource, StreamTick
 
@@ -59,7 +62,9 @@ class CountingSink:
 
     def commit(self, et, now=None):
         self.commits += 1
-        return {"committed": True, "rho": 1.0}
+        return {"committed": True, "rho": 1.0, "dropped": 0,
+                "probe_rounds": 0, "pressure": 0.0, "refs": 0,
+                "dict_hit_rate": None}
 
 
 class FlatConsumer:
@@ -106,28 +111,102 @@ def test_custom_source_sink_consumer_roundtrip():
 
 
 # ---------------------------------------------------------------------------
-# wrapper parity: the compat IngestionPipeline == builder-built pipeline
+# the push body: a golden run, the commit outcome, the spans of one tick
 # ---------------------------------------------------------------------------
+
+_GOLDEN = os.path.join(os.path.dirname(__file__), "data", "push_golden.json")
 
 
 @pytest.mark.parametrize("uncontrolled", [False, True])
 def test_wrapper_matches_builder_pipeline(uncontrolled):
-    kw = dict(seed=9, mean_rate=60, burst_multiplier=5.0)
-    old = IngestionPipeline(IngestConfig(), uncontrolled=uncontrolled,
-                            spill_dir=f"/tmp/repro_spill_par_a{uncontrolled}")
-    r_old = old.run(BurstyTweetSource(**kw).ticks(), max_ticks=50)
-    new = (PipelineBuilder(IngestConfig())
-           .with_source(BurstyTweetSource(**kw))
-           .uncontrolled(uncontrolled)
-           .spill_dir(f"/tmp/repro_spill_par_b{uncontrolled}")
-           .build())
-    r_new = new.run(max_ticks=50)
-    assert r_old.total_records == r_new.total_records
-    assert r_old.total_instructions == r_new.total_instructions
-    assert r_old.actions == r_new.actions
-    np.testing.assert_array_equal(r_old.samples["mu"], r_new.samples["mu"])
-    np.testing.assert_array_equal(r_old.samples["delay_s"],
-                                  r_new.samples["delay_s"])
+    """Golden run of the one push body: 50 ticks of a bursty stream give
+    the totals, actions and mu/delay traces recorded before the
+    uncontrolled and controlled pushes were folded into one function."""
+    want = json.load(open(_GOLDEN))[
+        "uncontrolled" if uncontrolled else "controlled"]
+    pipe = (PipelineBuilder(IngestConfig())
+            .with_source(BurstyTweetSource(seed=9, mean_rate=60,
+                                           burst_multiplier=5.0))
+            .uncontrolled(uncontrolled)
+            .spill_dir(f"/tmp/repro_spill_par_b{uncontrolled}")
+            .build())
+    rep = pipe.run(max_ticks=50)
+    assert rep.total_records == want["total_records"]
+    assert rep.total_instructions == want["total_instructions"]
+    assert rep.actions == want["actions"]
+    np.testing.assert_array_equal(rep.samples["mu"], want["mu"])
+    np.testing.assert_array_equal(rep.samples["delay_s"], want["delay_s"])
+
+
+def _tiny_records(t=0, n=6):
+    return [{"id": f"t{t}_{j}", "user": f"u{j % 3}",
+             "hashtags": [f"h{j % 2}"], "mentions": [f"u{(j + 1) % 4}"]}
+            for j in range(n)]
+
+
+_OUTCOME_KEYS = {"committed", "stats", "busy_s", "rho", "instructions",
+                 "dropped", "probe_rounds", "pressure", "refs",
+                 "dict_hit_rate"}
+
+
+@pytest.mark.parametrize("kind", ["raw", "compressed", "failed"])
+def test_commit_outcome_keys(kind):
+    """`GraphIngestor.push` returns one outcome shape on every commit
+    that lands, raw or rewritten by the dictionary; a failed commit
+    keeps the failure keys."""
+    from repro.compress import CompressingTransform, DictionaryStage
+    from repro.resilience import RetryPolicy
+
+    transform = TransformStage(max_edges_per_batch=64)
+    if kind == "compressed":
+        transform = CompressingTransform(transform, DictionaryStage())
+    et, _, _ = transform.encode(_tiny_records())
+    ing = GraphIngestor(init_store(256, 512),
+                        fail_hook=lambda: kind == "failed",
+                        retry_policy=RetryPolicy(), degrade_after=1)
+    out = ing.push(et, now=1.0)
+    if kind == "failed":
+        assert set(out) == {"committed", "retry_in_s", "degraded",
+                            "archived"}
+        assert not out["committed"] and out["archived"] == 1
+        return
+    assert set(out) == _OUTCOME_KEYS
+    assert out["committed"] and out["instructions"] > 0
+    if kind == "raw":
+        assert out["refs"] == 0 and out["dict_hit_rate"] is None
+    else:
+        assert isinstance(out["refs"], int)
+        assert isinstance(out["dict_hit_rate"], float)
+
+
+# the spans one tick writes, in the order they close: the benchmark's
+# span readers depend on these names and on their nesting under `tick`
+_TICK_SPANS = {
+    "uncontrolled": [
+        "filter", "transform.map", "transform.dedup", "transform.fetch",
+        "commit.upsert", "commit.wait", "commit.fetch", "commit.hooks",
+        "consume", "loop.fetch", "tick"],
+    "controlled": [
+        "filter", "sketch.update", "decide", "transform.map",
+        "transform.dedup", "transform.fetch", "rewrite.table",
+        "transform.fetch", "commit.upsert", "commit.wait", "commit.fetch",
+        "dict.admit", "commit.hooks", "consume", "loop.fetch", "tick"],
+}
+
+
+@pytest.mark.parametrize("mode", ["uncontrolled", "controlled"])
+def test_one_tick_writes_the_benchmark_spans(mode, tmp_path):
+    b = (PipelineBuilder(IngestConfig(store_nodes=256, store_edges=512,
+                                      max_edges_per_batch=64))
+         .measured_consumer().spill_dir(str(tmp_path)).with_telemetry())
+    if mode == "uncontrolled":
+        b.uncontrolled()
+    else:
+        b.with_sketch().with_compression()
+    pipe = b.build()
+    pipe.run(source_ticks=[StreamTick(1.0, _tiny_records())], max_ticks=1)
+    assert [e[0] for e in pipe.telemetry.events] == _TICK_SPANS[mode]
+    assert pipe.metrics.counters["commit"] == 1
 
 
 # ---------------------------------------------------------------------------

@@ -2,18 +2,20 @@
 import numpy as np
 import pytest
 
+from repro.api import PipelineBuilder
 from repro.configs.paper_ingest import IngestConfig
-from repro.core.pipeline import IngestionPipeline
 from repro.ingest.sources import BurstyTweetSource
 
 
+def _pipe(cfg, uncontrolled, compress, spill_dir):
+    return (PipelineBuilder(cfg).uncontrolled(uncontrolled)
+            .compressed(compress).spill_dir(spill_dir).build())
+
+
 def _run(uncontrolled, compress, ticks=150, seed=3, **cfg_kw):
-    cfg = IngestConfig(**cfg_kw)
     src = BurstyTweetSource(seed=seed)
-    pipe = IngestionPipeline(
-        cfg, uncontrolled=uncontrolled, compress=compress,
-        spill_dir=f"/tmp/repro_spill_sys_{uncontrolled}_{compress}",
-    )
+    pipe = _pipe(IngestConfig(**cfg_kw), uncontrolled, compress,
+                 f"/tmp/repro_spill_sys_{uncontrolled}_{compress}")
     return pipe.run(src.ticks(), max_ticks=ticks), pipe
 
 
@@ -42,13 +44,11 @@ def test_compression_better_during_bursts():
     # controller (which rightly throttles a *permanent* 5x storm)
     src = BurstyTweetSource(seed=5, p_burst_start=1.0, p_burst_end=0.0,
                             burst_hashtags=6, duplicate_frac=0.2)  # storm
-    pipe = IngestionPipeline(IngestConfig(), uncontrolled=True, compress=True,
-                             spill_dir="/tmp/repro_spill_b1")
+    pipe = _pipe(IngestConfig(), True, True, "/tmp/repro_spill_b1")
     r_burst = pipe.run(src.ticks(), max_ticks=80)
     src2 = BurstyTweetSource(seed=5, p_burst_start=0.0, n_hashtags=20_000,
                              duplicate_frac=0.05)  # diverse calm day
-    pipe2 = IngestionPipeline(IngestConfig(), uncontrolled=True, compress=True,
-                              spill_dir="/tmp/repro_spill_b2")
+    pipe2 = _pipe(IngestConfig(), True, True, "/tmp/repro_spill_b2")
     r_calm = pipe2.run(src2.ticks(), max_ticks=80)
     assert r_burst.mean_compression < r_calm.mean_compression
 
@@ -56,7 +56,7 @@ def test_compression_better_during_bursts():
 def test_store_consistent_with_stream():
     """Every unique node that entered the pipeline exists in the store."""
     r, pipe = _run(uncontrolled=False, compress=True, ticks=60)
-    store = pipe.ingestor.store
+    store = pipe.sink.ingestor.store
     assert int(store.n_nodes) > 0
     assert int(store.n_edges) > 0
     # edge-count conservation: stored counts == committed raw edges
